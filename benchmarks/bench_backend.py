@@ -6,13 +6,11 @@ on the protocol numbers (sends, deliveries, delivered fraction — the
 byte-identity the test suite asserts in full), and records rounds/sec
 and messages/sec side by side in ``BENCH_backend.json``.
 
-What the numbers mean depends on where Pallas runs (the JSON records
-it): on a TPU the kernels compile and the comparison measures the fused
-delivery sweep against the plain ``lax.scan`` body; everywhere else
 Pallas executes in interpret mode — byte-identical but paying the
 interpreter's lowering overhead — so the comparison documents the cost
-of the testing path, not a speedup.  ``pallas_mode`` in the JSON is the
-availability probe's note.
+of the testing path, not a speedup.  The kernels do not compile for the
+TPU (DESIGN.md §2.6), where the pallas backend is refused.
+``pallas_mode`` in the JSON is the availability probe's note.
 
     PYTHONPATH=src python benchmarks/bench_backend.py \
         --n 2048 --messages 4096 --rate 64 --window 512 \
@@ -123,6 +121,8 @@ def main() -> None:
                     help="CI-sized point (seconds, not minutes)")
     ap.add_argument("--out", default="BENCH_backend.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         args.n, args.messages, args.rate = 256, 512, 16.0
         args.window = 128

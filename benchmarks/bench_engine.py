@@ -57,6 +57,8 @@ def rows():
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, us, derived in rows():
         print(f"{name},{us:.0f},{derived:.2f}")
 

@@ -125,6 +125,8 @@ def main():
                     help="run the vec sweep through the streaming "
                          "windowed engine with this many live columns")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, us, derived in rows(args.engine, args.n, args.backend,
                                   args.window):
         print(f"{name},{us:.0f},{derived:.3f}")

@@ -148,6 +148,8 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.devices}").strip()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     anchor = None
     if args.floor_ref:
         if not os.path.exists(args.floor_ref):

@@ -184,6 +184,8 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.devices}").strip()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ref = None
     if args.assert_floor is not None:
         # read the reference before --out can overwrite the same file

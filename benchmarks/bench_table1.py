@@ -126,6 +126,8 @@ def main():
                     help="route the pc vec runs through the streaming "
                          "windowed engine with this many live columns")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, us, derived in rows(args.engine, args.n, args.backend,
                                   args.window):
         print(f"{name},{us:.2f},{derived:.2f}")

@@ -125,6 +125,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="BENCH_throughput.json")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, us, derived in rows(args.n, args.messages, args.rate,
                                   args.window, args.k, args.backend,
                                   args.topology, args.traffic, args.seg_len,

@@ -217,7 +217,9 @@ BACKENDS.register("jax", BackendEntry(
     "device-side lax.scan, DESIGN.md §2.7)", _probe_jax))
 BACKENDS.register("pallas", BackendEntry(
     "pallas", "fused Pallas delivery-sweep kernels in the round body "
-    "(vecsim.kernels, DESIGN.md §2.6); never auto-selected off-TPU",
+    "(vecsim.kernels, DESIGN.md §2.6): interpret mode on CPU, a "
+    "byte-parity check; they do not compile for the TPU and are never "
+    "auto-selected",
     _probe_pallas))
 
 

@@ -147,9 +147,8 @@ def select_engine(spec: RunSpec, scn: VecScenario
 
     An explicit ``backend="pallas"`` fails here — eagerly, with a
     :class:`SpecError` naming the probe's reason — when the kernels
-    cannot initialize; ``backend="auto"`` instead quietly resolves to
-    the jax backend wherever Pallas is unavailable (or interpret-only,
-    which would be byte-identical but slower)."""
+    cannot run here (on a TPU they do not compile); ``backend="auto"``
+    is always the jax backend."""
     if spec.backend == "pallas":
         from .registry import BACKENDS
         ok, note = BACKENDS.get("pallas").probe()

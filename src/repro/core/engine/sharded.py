@@ -41,7 +41,10 @@ def run_engine_sharded(cfg: EngineConfig, sched: Schedule, adj0, delay0,
                        mesh=None):
     """Same contract as ``run_engine`` but state sharded over 'procs'."""
     if mesh is None:
-        mesh = jax.make_mesh((jax.device_count(),), ("procs",))
+        # Auto axes: the scatters in step.py leave their output sharding
+        # to the partitioner (make_mesh defaults to Explicit axes)
+        mesh = jax.make_mesh((jax.device_count(),), ("procs",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
     n_dev = int(np.prod(mesh.devices.shape))
     cfg, adj0, delay0 = pad_instance(cfg, adj0, delay0, n_dev)
 

@@ -3,8 +3,8 @@
 
   * ``kernel.py`` — the Pallas kernels (column-tiled grid, row-loop
     scatter-min);
-  * ``ops.py``    — padding/dispatch wrappers, the availability probe,
-    interpret-mode resolution (this module's public surface);
+  * ``ops.py``    — padding/dispatch wrappers and the availability
+    probe (this module's public surface);
   * ``ref.py``    — plain-lax references each kernel unit-tests against.
 
 Importing this package is cheap and jax-free; jax/pallas load on first

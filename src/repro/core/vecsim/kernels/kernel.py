@@ -37,11 +37,12 @@ Counter outputs are int32: per-tile partials are bounded by N·BW
 (rows times tile width), which holds far past the engine's memory
 ceiling; the int64 NetStats math happens in lax outside the kernels.
 
-``interpret=True`` runs every kernel through the Pallas interpreter
-(plain jitted XLA ops) — that is the CPU testing mode under which the
-whole scenario matrix cross-validates byte-identical against the jax
-backend.  Compiled TPU execution additionally wants the window padded
-to the 128-lane tile (``ops.py`` pads) and N a multiple of 8.
+``ops.py`` runs every kernel through the Pallas interpreter (plain
+jitted XLA ops) — the CPU testing mode under which the whole scenario
+matrix cross-validates byte-identical against the jax backend.  These
+kernels do not compile for the TPU (DESIGN.md §2.6): Mosaic rejects
+the dynamic row read of a value in ``_scatter_links`` and the 1-D bool
+blocks, and a whole-N block outgrows VMEM at real N.
 """
 
 from __future__ import annotations

@@ -6,17 +6,21 @@ per-tile stat partials, and slices the planes back — callers (the span
 runners in ``sim.py`` / ``shard/spanner.py`` and the windowed driver's
 retirement sweep in ``stream.py``) see exact ``(N, W)`` semantics.
 
-``interpret=None`` resolves via :func:`default_interpret`: compiled
-kernels on a real TPU, the Pallas interpreter everywhere else.  The
-interpreter lowers to ordinary jitted XLA ops, so interpret-mode
-backends are byte-identical to (and test against) the jax backend on
-CPU; the padding columns are inert (``arr=INF``, ``delivered=-1``,
-``is_app=False``) and can never deliver, flush or count.
+Every op runs through the Pallas interpreter, the only mode these
+kernels run in: the interpreter lowers to ordinary jitted XLA ops, so
+the pallas backend is byte-identical to (and tests against) the jax
+backend on CPU; the padding columns are inert (``arr=INF``,
+``delivered=-1``, ``is_app=False``) and can never deliver, flush or
+count.
 
-Availability is probed lazily (:func:`pallas_available`) so the numpy
-backend keeps working on hosts without jax; ``repro.api`` surfaces the
-probe's note in ``--list`` and turns a failed probe into a
-``SpecError`` when ``backend="pallas"`` is requested explicitly.
+The kernels do not compile for the TPU (DESIGN.md §2.6): Mosaic
+rejects the dynamic row read in the scatter loop and the 1-D bool
+blocks, and every block holds the whole process axis, which outgrows
+VMEM at real N.  :func:`pallas_available` therefore reports them
+unavailable on a TPU; ``repro.api`` surfaces the probe's note in
+``--list`` and turns a failed probe into a ``SpecError`` when
+``backend="pallas"`` is requested explicitly, so a TPU run never falls
+back to the interpreter.
 """
 
 from __future__ import annotations
@@ -29,7 +33,7 @@ import numpy as np
 from ..scenario import INF
 
 __all__ = ["PallasUnavailableError", "pallas_available", "require_pallas",
-           "default_interpret", "deliver_sweep", "fused_sweep",
+           "deliver_sweep", "fused_sweep",
            "frontier_sweep", "retire_scan", "retire_scan_jit",
            "retire_reduce", "retire_reduce_jit", "latency_hist",
            "latency_hist_jit", "slot_frontier", "ring_apply",
@@ -55,9 +59,10 @@ def pallas_available() -> Tuple[bool, str]:
     except Exception as exc:  # pragma: no cover - environment-dependent
         return False, f"jax backend init failed: {exc}"
     if platform == "tpu":
-        return True, "compiled TPU kernels"
+        return False, ("the delivery-sweep kernels do not compile for the "
+                       "TPU (DESIGN.md §2.6)")
     return True, (f"interpret mode on {platform} (byte-identical to the "
-                  "jax backend; compiled speed needs a TPU)")
+                  "jax backend; a correctness check, not a speed path)")
 
 
 def require_pallas() -> None:
@@ -66,18 +71,6 @@ def require_pallas() -> None:
         raise PallasUnavailableError(
             f"backend='pallas' requested but Pallas cannot initialize "
             f"({note}); use backend='jax' or 'auto'")
-
-
-def default_interpret() -> bool:
-    """Interpret unless an actual TPU can compile the kernels."""
-    ok, note = pallas_available()
-    return not (ok and note == "compiled TPU kernels")
-
-
-def _resolve(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return default_interpret()
-    return bool(interpret)
 
 
 def _tiles(w: int, block_w: Optional[int]) -> Tuple[int, int, int]:
@@ -154,8 +147,7 @@ def popcount_bytes(x):
 
 
 def deliver_sweep(arr, delivered, crashed, is_app, t, *,
-                  block_w: Optional[int] = None,
-                  interpret: Optional[bool] = None):
+                  block_w: Optional[int] = None):
     """Phase 5 over the live window: ``(delivered', napp, nping)``."""
     import jax
     import jax.numpy as jnp
@@ -184,7 +176,7 @@ def deliver_sweep(arr, delivered, crashed, is_app, t, *,
             jax.ShapeDtypeStruct((nt, n), jnp.int32),
             jax.ShapeDtypeStruct((nt, n), jnp.int32),
         ],
-        interpret=_resolve(interpret),
+        interpret=True,
     )(_t_arr(t), crashed, _pad_cols(is_app, wp, False),
       _pad_cols(arr, wp, _INF), _pad_cols(delivered, wp, -1))
     return (out_del[:, :w], napp.sum(axis=0).astype(jnp.int32),
@@ -192,8 +184,7 @@ def deliver_sweep(arr, delivered, crashed, is_app, t, *,
 
 
 def fused_sweep(arr, delivered, crashed, adj, delay, fwd_ok, is_app, t, *,
-                block_w: Optional[int] = None,
-                interpret: Optional[bool] = None):
+                block_w: Optional[int] = None):
     """Gating-free fused sweep: ``(arr', delivered', napp, nping)``."""
     import jax
     import jax.numpy as jnp
@@ -228,7 +219,7 @@ def fused_sweep(arr, delivered, crashed, adj, delay, fwd_ok, is_app, t, *,
             jax.ShapeDtypeStruct((nt, n), jnp.int32),
             jax.ShapeDtypeStruct((nt, n), jnp.int32),
         ],
-        interpret=_resolve(interpret),
+        interpret=True,
     )(_t_arr(t), crashed, _pad_cols(is_app, wp, False), adj, delay, fwd_ok,
       _pad_cols(arr, wp, _INF), _pad_cols(delivered, wp, -1))
     return (out_arr[:, :w], out_del[:, :w],
@@ -237,8 +228,7 @@ def fused_sweep(arr, delivered, crashed, adj, delay, fwd_ok, is_app, t, *,
 
 
 def frontier_sweep(arr, delivered, adj, delay, gate, do, fwd_ok, is_app,
-                   t, *, block_w: Optional[int] = None,
-                   interpret: Optional[bool] = None):
+                   t, *, block_w: Optional[int] = None):
     """Gated fused sweep (flush + forward): ``(arr', flush_sent)``."""
     import jax
     import jax.numpy as jnp
@@ -270,7 +260,7 @@ def frontier_sweep(arr, delivered, adj, delay, gate, do, fwd_ok, is_app,
             jax.ShapeDtypeStruct((n, wp), jnp.int32),
             jax.ShapeDtypeStruct((nt,), jnp.int32),
         ],
-        interpret=_resolve(interpret),
+        interpret=True,
     )(_t_arr(t), adj, delay, gate, do, fwd_ok,
       _pad_cols(is_app, wp, False), _pad_cols(delivered, wp, -1),
       _pad_cols(arr, wp, _INF))
@@ -278,8 +268,7 @@ def frontier_sweep(arr, delivered, adj, delay, gate, do, fwd_ok, is_app,
 
 
 def retire_scan(delivered, crashed, min_gate, *,
-                block_w: Optional[int] = None,
-                interpret: Optional[bool] = None):
+                block_w: Optional[int] = None):
     """Per-column retirement reductions: ``(cnt, alivedel, blocked)``."""
     import jax
     import jax.numpy as jnp
@@ -306,15 +295,14 @@ def retire_scan(delivered, crashed, min_gate, *,
             jax.ShapeDtypeStruct((1, wp), jnp.int32),
             jax.ShapeDtypeStruct((1, wp), jnp.int32),
         ],
-        interpret=_resolve(interpret),
+        interpret=True,
     )(crashed, jnp.asarray(min_gate, jnp.int32),
       _pad_cols(jnp.asarray(delivered, jnp.int32), wp, -1))
     return cnt[0, :w], alivedel[0, :w], blocked[0, :w]
 
 
 def retire_reduce(arr, delivered, crashed, min_gate, rounds, *,
-                  block_w: Optional[int] = None,
-                  interpret: Optional[bool] = None):
+                  block_w: Optional[int] = None):
     """Per-column retirement *and* record reductions:
     ``(cnt, alivedel, blocked, arrcnt, sumdel)`` — the
     :func:`retire_scan` triple plus the first-receipt count and the
@@ -340,7 +328,7 @@ def retire_reduce(arr, delivered, crashed, min_gate, rounds, *,
         ],
         out_specs=[pl.BlockSpec((1, bw), lambda i: (0, i))] * 5,
         out_shape=[jax.ShapeDtypeStruct((1, wp), jnp.int32)] * 5,
-        interpret=_resolve(interpret),
+        interpret=True,
     )(crashed, jnp.asarray(min_gate, jnp.int32), _t_arr(rounds),
       _pad_cols(jnp.asarray(arr, jnp.int32), wp, INF),
       _pad_cols(jnp.asarray(delivered, jnp.int32), wp, -1))
@@ -348,17 +336,14 @@ def retire_reduce(arr, delivered, crashed, min_gate, rounds, *,
 
 
 @functools.lru_cache(maxsize=None)
-def retire_reduce_jit(block_w: Optional[int] = None,
-                      interpret: Optional[bool] = None):
+def retire_reduce_jit(block_w: Optional[int] = None):
     """Cached jitted :func:`retire_reduce` (same treatment as
     :func:`retire_scan_jit`)."""
     import jax
-    return jax.jit(functools.partial(retire_reduce, block_w=block_w,
-                                     interpret=interpret))
+    return jax.jit(functools.partial(retire_reduce, block_w=block_w))
 
 
-def latency_hist(base, delivered, *, block_w: Optional[int] = None,
-                 interpret: Optional[bool] = None):
+def latency_hist(base, delivered, *, block_w: Optional[int] = None):
     """Per-column ``(W, NB)`` delivery-latency histogram: row p of
     column m counts in bucket(delivered[p, m] - base[m]) when the row
     delivered and the column carries a latency base (``base >= 0``).
@@ -380,38 +365,33 @@ def latency_hist(base, delivered, *, block_w: Optional[int] = None,
         ],
         out_specs=pl.BlockSpec((bw, NB), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((wp, NB), jnp.int32),
-        interpret=_resolve(interpret),
+        interpret=True,
     )(_pad_cols(jnp.asarray(base, jnp.int32), wp, -1),
       _pad_cols(jnp.asarray(delivered, jnp.int32), wp, -1))
     return out[:w]
 
 
 @functools.lru_cache(maxsize=None)
-def latency_hist_jit(block_w: Optional[int] = None,
-                     interpret: Optional[bool] = None):
+def latency_hist_jit(block_w: Optional[int] = None):
     """Cached jitted :func:`latency_hist` (same treatment as
     :func:`retire_reduce_jit`)."""
     import jax
-    return jax.jit(functools.partial(latency_hist, block_w=block_w,
-                                     interpret=interpret))
+    return jax.jit(functools.partial(latency_hist, block_w=block_w))
 
 
 @functools.lru_cache(maxsize=None)
-def retire_scan_jit(block_w: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+def retire_scan_jit(block_w: Optional[int] = None):
     """Cached jitted :func:`retire_scan` for eager per-segment host
     calls (the windowed driver's retirement sweep): the span runners
     amortize their traces through ``lru_cache``d jitted scans, and this
     gives the host-side reduction the same treatment — one trace per
     plane shape instead of a fresh interpreter lowering every sweep."""
     import jax
-    return jax.jit(functools.partial(retire_scan, block_w=block_w,
-                                     interpret=interpret))
+    return jax.jit(functools.partial(retire_scan, block_w=block_w))
 
 
 def slot_frontier(delivered, gate_k, delay_k, do_k, fwd_k, is_app, t, *,
-                  gating: bool, block_w: Optional[int] = None,
-                  interpret: Optional[bool] = None):
+                  gating: bool, block_w: Optional[int] = None):
     """One slot's ring contribution plane: ``(vals, win_cnt)``."""
     import jax
     import jax.numpy as jnp
@@ -440,14 +420,13 @@ def slot_frontier(delivered, gate_k, delay_k, do_k, fwd_k, is_app, t, *,
             jax.ShapeDtypeStruct((n, wp), jnp.int32),
             jax.ShapeDtypeStruct((nt,), jnp.int32),
         ],
-        interpret=_resolve(interpret),
+        interpret=True,
     )(_t_arr(t), gate_k, delay_k, do_k, fwd_k,
       _pad_cols(is_app, wp, False), _pad_cols(delivered, wp, -1))
     return vals[:, :w], win.sum().astype(jnp.int32)
 
 
-def ring_apply(arr, vals, tgt, off, *, block_w: Optional[int] = None,
-               interpret: Optional[bool] = None):
+def ring_apply(arr, vals, tgt, off, *, block_w: Optional[int] = None):
     """Owner-local scatter-min of a visiting ring plane: ``arr'``."""
     import jax
     import jax.numpy as jnp
@@ -467,7 +446,7 @@ def ring_apply(arr, vals, tgt, off, *, block_w: Optional[int] = None,
         ],
         out_specs=pl.BlockSpec((n, bw), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, wp), jnp.int32),
-        interpret=_resolve(interpret),
+        interpret=True,
     )(jnp.asarray(off, jnp.int32).reshape(1), tgt,
       _pad_cols(vals, wp, _INF), _pad_cols(arr, wp, _INF))
     return out[:, :w]

@@ -677,8 +677,8 @@ def execute_sharded(scn: VecScenario, window: int,
     engine *is* a jax mesh program, so ``backend`` only chooses how the
     per-shard round body executes: ``"jax"`` (plain lax, the default)
     or ``"pallas"`` (per-shard delivery-sweep kernel launches inside
-    ``shard_map``, DESIGN.md §2.6); ``"auto"`` resolves like the other
-    engines (pallas only where the kernels compile).
+    ``shard_map``, DESIGN.md §2.6); ``"auto"`` resolves to jax like the
+    other engines.
 
     ``scan`` picks the segment loop (DESIGN.md §2.7/§2.8): ``"on"``
     (and ``"auto"``) runs each segment as one device-resident

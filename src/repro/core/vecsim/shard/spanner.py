@@ -91,13 +91,9 @@ def resolve_shard_backend(backend: str) -> str:
     """Validate/resolve the sharded engine's round-body backend — the
     one place the accepted names live.  ``"jax"`` passes through,
     ``"pallas"`` requires the kernels to initialize, and ``"auto"``
-    resolves like the other engines (numpy can never shard, so auto
-    lands on jax wherever Pallas does not compile)."""
+    resolves to jax like the other engines."""
     if backend == "auto":
-        from ..sim import resolve_backend
-        backend = resolve_backend("auto")
-        if backend == "numpy":  # pragma: no cover - needs jax to get here
-            backend = "jax"
+        backend = "jax"
     if backend == "pallas":
         from .. import kernels
         kernels.require_pallas()
@@ -190,8 +186,6 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
     asserts it."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     backend = resolve_shard_backend(backend)
@@ -441,16 +435,16 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
         def span(state, sched, ts):
             return jax.lax.scan(lambda c, t: step(sched, c, t), state, ts)
 
-    # check_rep=False: lax.cond trips shard_map's replication checker
+    # check_vma=False: lax.cond trips shard_map's replication checker
     # (jax-ml/jax known limitation); the stats output really is
     # replicated — it comes out of an explicit psum on every branch.
-    _run = jax.jit(shard_map(
+    _run = jax.jit(jax.shard_map(
         span, mesh=mesh,
         in_specs=((P("shard"), P(), P(), P(), P()) if scan
                   else (P("shard"), P(), P())),
         out_specs=((P("shard"), P(), P()) if scan
                    else (P("shard"), P())),
-        check_rep=False),
+        check_vma=False),
         # scanned segments own the live buffers for many rounds: donate
         # them so the carry updates in place instead of doubling the
         # peak (N, W) footprint
@@ -460,7 +454,7 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
         # x64 so the int64 stats accumulators (and their psum) are
         # honored; every state/schedule array carries an explicit dtype,
         # so nothing else widens — byte-parity with the windowed series.
-        with enable_x64():
+        with jax.enable_x64(True):
             if scan:
                 return _run(state, sched, ts, origins, rounds)
             return _run(state, sched, ts)
@@ -507,8 +501,6 @@ def shard_fast_span_runner(n_devices: int, classes_sig: tuple):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..kernels import pack_columns, popcount_bytes, unpack_columns
@@ -629,15 +621,15 @@ def shard_fast_span_runner(n_devices: int, classes_sig: tuple):
                     for x in _column_partials(state, origins, rounds, off))
         return state, stats, red
 
-    _run = jax.jit(shard_map(
+    _run = jax.jit(jax.shard_map(
         span, mesh=mesh,
         in_specs=(P("shard"), P("shard"), P(), P(), P(), P(), P()),
         out_specs=(P("shard"), P(), P()),
-        check_rep=False),
+        check_vma=False),
         donate_argnums=(0,))
 
     def run(state, tabs, ia_pack, sched, ts, origins, rounds):
-        with enable_x64():
+        with jax.enable_x64(True):
             return _run(state, tabs, ia_pack, sched, ts, origins, rounds)
 
     run.jitted = _run
@@ -656,8 +648,6 @@ def shard_retire_kernels(n_devices: int):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = shard_mesh(n_devices)
@@ -670,7 +660,7 @@ def shard_retire_kernels(n_devices: int):
         out = _column_partials(state, origins, rounds, off)
         return tuple(jax.lax.psum(x, "shard") for x in out)
 
-    _reduce = jax.jit(shard_map(
+    _reduce = jax.jit(jax.shard_map(
         reduce_fn, mesh=mesh,
         in_specs=(P("shard"), P(), P()),
         out_specs=P()))
@@ -694,17 +684,17 @@ def shard_retire_kernels(n_devices: int):
         return (arr, delivered, adj, delay, active, gate, flush, ping,
                 crashed, ever_del)
 
-    _apply = jax.jit(shard_map(
+    _apply = jax.jit(jax.shard_map(
         apply_fn, mesh=mesh,
         in_specs=(P("shard"), P(), P(), P()),
         out_specs=P("shard")))
 
     def reduce_run(state, origins, rounds):
-        with enable_x64():
+        with jax.enable_x64(True):
             return _reduce(state, origins, rounds)
 
     def apply_run(state, retire, app_retire, hung):
-        with enable_x64():
+        with jax.enable_x64(True):
             return _apply(state, retire, app_retire, hung)
 
     return reduce_run, apply_run
@@ -738,8 +728,6 @@ def shard_hist_runner(n_devices: int):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ....obs.hist import NB
@@ -763,13 +751,13 @@ def shard_hist_runner(n_devices: int):
                         + [valid.sum().astype(jnp.int64)])
         return jax.lax.psum(jnp.diff(cum), "shard")
 
-    _run = jax.jit(shard_map(
+    _run = jax.jit(jax.shard_map(
         hist_fn, mesh=mesh,
         in_specs=(P("shard"), P(), P()),
         out_specs=P()))
 
     def run(delivered, cols, base):
-        with enable_x64():
+        with jax.enable_x64(True):
             return _run(delivered, cols, base)
 
     return run

@@ -35,7 +35,7 @@ Three backends execute the identical semantics:
     pure scatter/gather so the body matches ``repro.core.engine.step``;
   * ``pallas`` — the same scan with the per-round delivery sweep fused
     into Pallas kernels (``vecsim.kernels``, DESIGN.md §2.6); interpret
-    mode on CPU, compiled on TPU.
+    mode on CPU only, as the kernels do not compile for the TPU.
 
 Tests assert the backends produce byte-identical ``delivered``
 matrices and per-round stats series on random scenarios.
@@ -476,8 +476,6 @@ def jax_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
     import jax
     import jax.numpy as jnp
 
-    from jax.experimental import enable_x64
-
     inf = jnp.int32(INF)
 
     def scatter_min(arr, rows, vals, valid):
@@ -565,16 +563,16 @@ def jax_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
         # x64 so the int64 stats accumulators are honored; every array in
         # the carry/schedule carries an explicit dtype, so nothing else
         # widens (tests assert byte-parity with the int64 numpy series)
-        with enable_x64():
+        with jax.enable_x64(True):
             return _run(state, sched, ts)
 
+    run.jitted = _run
     return run
 
 
 @functools.lru_cache(maxsize=None)
 def pallas_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
-                       gating: bool = True,
-                       interpret: Optional[bool] = None):
+                       gating: bool = True):
     """Jitted ``(state, sched, ts) -> (state, stats)`` span runner with
     the per-round delivery sweep fused into Pallas kernels (DESIGN.md
     §2.6) — same contract and byte-identical results as
@@ -589,8 +587,6 @@ def pallas_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
     """
     import jax
     import jax.numpy as jnp
-
-    from jax.experimental import enable_x64
 
     from . import kernels as kx
 
@@ -608,7 +604,7 @@ def pallas_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
         if pc and gating:
             # -- 5. deliver-sweep kernel ------------------------------------ #
             delivered, napp, nping = kx.deliver_sweep(
-                arr, delivered, crashed, is_app, t, interpret=interpret)
+                arr, delivered, crashed, is_app, t)
             # -- 6. pong detection (cross-column gather; lax) --------------- #
             fire = pong_fire(delivered, adj, gate, flush, ping, crashed)
             flush = jnp.where(fire, t + pong_delay, flush)
@@ -622,8 +618,7 @@ def pallas_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
             fwd_ok = (active & (gk_eff < 0) & (adj >= 0)
                       & ~crashed[:, None])
             arr, flush_sent = kx.frontier_sweep(
-                arr, delivered, adj, delay, gate, do, fwd_ok, is_app, t,
-                interpret=interpret)
+                arr, delivered, adj, delay, gate, do, fwd_ok, is_app, t)
             stats = stats.at[3].set(flush_sent.astype(jnp.int64))
             cleared = flush == t
             gate = jnp.where(cleared, -1, gate)
@@ -633,8 +628,7 @@ def pallas_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
             # -- 5+8. single fused deliver + forward sweep kernel ----------- #
             fwd_ok = (active & (gate < 0) & (adj >= 0) & ~crashed[:, None])
             arr, delivered, napp, nping = kx.fused_sweep(
-                arr, delivered, crashed, adj, delay, fwd_ok, is_app, t,
-                interpret=interpret)
+                arr, delivered, crashed, adj, delay, fwd_ok, is_app, t)
 
         elig_cnt = fwd_ok.sum(axis=1).astype(jnp.int64)
         napp = napp.astype(jnp.int64)
@@ -660,7 +654,7 @@ def pallas_span_runner(k: int, pc: bool, always_gate: bool, pong_delay: int,
         return jax.lax.scan(lambda c, t: step(sched, c, t), state, ts)
 
     def run(state, sched, ts):
-        with enable_x64():
+        with jax.enable_x64(True):
             return _run(state, sched, ts)
 
     return run
@@ -698,21 +692,13 @@ def _run_jax(scn: VecScenario, snapshot_round: Optional[int],
 def resolve_backend(backend: str) -> str:
     """Resolve ``"auto"`` and validate explicit backend names.
 
-    ``auto`` picks jax when importable (numpy otherwise) — and the
-    fused Pallas kernels only when an actual TPU can compile them;
-    anywhere Pallas is unavailable or interpret-only, auto falls back
-    to the jax backend.  ``backend="pallas"`` asked for by name raises
-    :class:`~repro.core.vecsim.kernels.PallasUnavailableError` when the
-    kernels cannot initialize."""
+    ``auto`` is the jax backend on every platform.  The Pallas kernels
+    run only in interpret mode on CPU, where they are a byte-parity
+    check and not a speed path, and they do not compile for the TPU
+    (DESIGN.md §2.6).  ``backend="pallas"`` asked for by name raises
+    :class:`~repro.core.vecsim.kernels.PallasUnavailableError` where the
+    kernels cannot run."""
     if backend == "auto":
-        try:
-            import jax
-        except ImportError:
-            return "numpy"
-        from . import kernels
-        ok, _ = kernels.pallas_available()
-        if ok and jax.default_backend() == "tpu":
-            return "pallas"
         return "jax"
     if backend == "pallas":
         from . import kernels

@@ -36,8 +36,8 @@ def rglru_scan_kernel(a_ref, b_ref, h0_ref, h_ref, state_ref):
         # Index every axis with a slice: a bare int index reaches the
         # swap discharge rule as a scalar without a .shape and crashes
         # interpret mode, so the leading block axis uses pl.dslice too.
-        pl.store(h_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 h[:, None, :].astype(h_ref.dtype))
+        h_ref[pl.ds(0, 1), pl.ds(t, 1), :] = h[:, None, :].astype(
+            h_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, a.shape[0], body, state_ref[...])

@@ -101,7 +101,7 @@ def lower_compile(cfg, shape, mesh, *, unroll=False, remat="dots",
             lambda s: NamedSharding(mesh, s), tree,
             is_leaf=lambda t: isinstance(t, jax.sharding.PartitionSpec))
 
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn,
                          in_shardings=to_sharding(in_specs),
                          out_shardings=(to_sharding(out_specs)

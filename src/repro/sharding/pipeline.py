@@ -81,12 +81,11 @@ def pipeline(stage_fn: Callable, mesh: Mesh, axis: str = "stage",
             # zeros), so a psum over the stage axis replicates the result
             return jax.lax.psum(outs, axis)
 
-        from jax.experimental.shard_map import shard_map
-        run_sharded = shard_map(
+        run_sharded = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis), P()),       # params sharded, mb replicated
             out_specs=P(),                  # replicated output
-            check_rep=False,
+            check_vma=False,
         )
         return run_sharded(params, mb)
 

@@ -159,15 +159,11 @@ def named(mesh: Mesh, spec_tree):
 
 def reshard_tree(tree, axes, policy: str = "tp"):
     """Best-effort re-shard of a param tree to ``policy`` rules under the
-    ambient mesh (no-op without one).  Used to hoist FSDP->TP parameter
-    all-gathers to once-per-step instead of once-per-microbatch: the
-    forward/backward consume the TP view while optimizer state stays
-    fully sharded (§Perf)."""
-    import warnings
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
+    mesh installed by ``jax.set_mesh`` (no-op without one).  Used to
+    hoist FSDP->TP parameter all-gathers to once-per-step instead of
+    once-per-microbatch: the forward/backward consume the TP view while
+    optimizer state stays fully sharded (§Perf)."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return tree
     rules = rules_for(policy, mesh)
@@ -182,13 +178,10 @@ def constrain(x, *spec):
 
     Model code calls this at sharding-critical intermediates (e.g. MoE
     dispatch buffers) so the SPMD partitioner keeps them distributed
-    instead of falling back to replicate+all-reduce; on meshless CPU runs
-    it is a no-op, keeping smoke tests mesh-free."""
-    import warnings
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
+    instead of falling back to replicate+all-reduce.  The mesh is the one
+    installed by ``jax.set_mesh``; without one it is a no-op, keeping
+    smoke tests mesh-free."""
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return x
     names = set(mesh.axis_names)

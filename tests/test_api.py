@@ -439,3 +439,27 @@ def test_cli_list_is_a_discovery_surface(capsys):
     assert "splitmix64" in out
     assert "CausalityViolationError" in out
     assert "append-only JSONL stream" in out
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir_is_fixed_or_from_env(tmp_path, from_env):
+    """The entry points' compile cache: ``JAX_COMPILATION_CACHE_DIR``
+    when set, otherwise ``.jax_cache`` in the checkout — checked in a
+    child so this process never turns the cache on."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    want = os.path.join(root, ".jax_cache")
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = want = str(tmp_path)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; from repro.compile_cache import enable_compile_cache;"
+         " print(enable_compile_cache());"
+         " print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, env=env, check=True)
+    assert out.stdout.split() == [want, want], out.stdout + out.stderr

@@ -99,12 +99,6 @@ def test_cost_analysis_counts_scan_body_once():
 # ------------------------------------------------------------------ #
 # sharding policy
 # ------------------------------------------------------------------ #
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="the subprocess snippet builds its mesh with "
-           "jax.sharding.AxisType (explicit-sharding API, jax >= 0.5.x); "
-           "the pinned jax in this environment predates it, so the "
-           "snippet can only fail on import — skipped, not broken")
 def test_policy_specs_respect_divisibility_subprocess():
     """grok's 8 experts don't divide model=16 -> d_ff TP fallback; qwen3-
     moe's 128 experts shard on model.  Needs a mesh => subprocess."""
@@ -145,12 +139,6 @@ def test_policy_specs_respect_divisibility_subprocess():
     assert "POLICY_OK" in out.stdout, out.stdout + out.stderr
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="the subprocess snippet builds its mesh with "
-           "jax.sharding.AxisType (explicit-sharding API, jax >= 0.5.x); "
-           "the pinned jax in this environment predates it, so the "
-           "snippet can only fail on import — skipped, not broken")
 def test_reduced_production_cell_compiles_subprocess():
     """A smoke-sized train cell lowers+compiles with full shardings on a
     forced 8-device (2x4) mesh — the dry-run pipeline end to end."""

@@ -6,9 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 SNIPPET = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -59,12 +56,6 @@ SNIPPET = textwrap.dedent("""
 """)
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="the subprocess snippet builds its mesh with "
-           "jax.sharding.AxisType (explicit-sharding API, jax >= 0.5.x); "
-           "the pinned jax in this environment predates it, so the "
-           "snippet can only fail on import — skipped, not broken")
 def test_gpipe_pipeline_matches_reference_subprocess():
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run([sys.executable, "-c", SNIPPET],

@@ -187,12 +187,6 @@ def test_serving_batches_share_decode_ticks(served_model):
     assert eng.ticks <= 6 * 7 / 2, eng.ticks  # well under serial 42
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="the subprocess snippet builds its meshes with "
-           "jax.sharding.AxisType (explicit-sharding API, jax >= 0.5.x); "
-           "the pinned jax in this environment predates it, so the "
-           "snippet can only fail on import — skipped, not broken")
 def test_checkpoint_cross_mesh_reshard_subprocess(tmp_path):
     """FT at fleet scale: params saved under one mesh topology restore
     under a different one (the manifest is topology-free; shardings are

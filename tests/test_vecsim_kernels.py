@@ -259,7 +259,7 @@ def test_pallas_sharded_multi_device_matrix(shards, cases):
 # --------------------------------------------------------------------- #
 def test_resolve_backend_accepts_pallas():
     assert resolve_backend("pallas") == "pallas"
-    assert resolve_backend("auto") in ("numpy", "jax", "pallas")
+    assert resolve_backend("auto") == "jax"
     with pytest.raises(ValueError):
         resolve_backend("cuda")
 
@@ -268,6 +268,29 @@ def test_pallas_available_probe_shape():
     ok, note = kx.pallas_available()
     assert isinstance(ok, bool) and isinstance(note, str) and note
     assert ok, "jax is importable here, so the probe must succeed"
+
+
+def test_pallas_refused_on_tpu(monkeypatch):
+    """On a TPU the kernels do not compile: the probe says so, an
+    explicit backend="pallas" raises before any run, and auto stays on
+    jax — nothing falls back to interpret mode."""
+    from repro.api import (RunSpec, SpecError, TrafficSpec, build_scenario,
+                           select_engine)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kx.pallas_available.cache_clear()
+    try:
+        ok, note = kx.pallas_available()
+        assert not ok and "TPU" in note
+        assert resolve_backend("auto") == "jax"
+        with pytest.raises(kx.PallasUnavailableError):
+            resolve_backend("pallas")
+        spec = RunSpec(protocol="pc", n=64, backend="pallas",
+                       traffic=TrafficSpec(kind="poisson", rate=2.0,
+                                           messages=8))
+        with pytest.raises(SpecError, match="do not compile"):
+            select_engine(spec, build_scenario(spec))
+    finally:
+        kx.pallas_available.cache_clear()
 
 
 def test_api_run_pallas_report_matches_jax():

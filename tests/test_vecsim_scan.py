@@ -276,7 +276,7 @@ def test_scan_pallas_backend_matrix_subprocess():
 # --------------------------------------------------------------------- #
 def _scan_lowering(n_devices, scn, w, seg_len):
     """Lower the scanned span runner exactly as the driver invokes it."""
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.core.vecsim.shard.spanner import (STATE_KEYS,
                                                  shard_span_runner)
@@ -296,7 +296,7 @@ def _scan_lowering(n_devices, scn, w, seg_len):
                                gating=scn.n_adds > 0, backend="jax",
                                scan=True)
     origins = np.full(w, -1, np.int32)
-    with enable_x64():
+    with jax.enable_x64(True):
         return runner.jitted.lower(state, sst, ts, origins,
                                    np.int32(scn.rounds)), state
 
