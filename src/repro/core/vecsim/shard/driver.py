@@ -272,7 +272,14 @@ class ShardedStepper:
         self.hist = obs is not None and obs.histograms
         self._rec = obs.spans if obs is not None else NULL_RECORDER
         self._sid = {name: self._rec.name(f"segment.{name}")
-                     for name in ("stage", "dispatch", "block", "retire")}
+                     for name in ("stage", "dispatch", "block", "retire",
+                                  "retire.pull", "retire.gather",
+                                  "retire.fold", "retire.apply")}
+        self._cid = {name: self._rec.name(f"retire.{name}")
+                     for name in ("columns", "hist_bytes")}
+        # segment programs dispatched so far, each with the abstract
+        # arguments of its first dispatch (program_texts)
+        self._programs: Dict[object, tuple] = {}
         # flight recorder (repro.obs.flight): host-side provenance
         # hooks riding the retiring-column gather — O(sample) transfer,
         # segment bodies untouched
@@ -295,14 +302,14 @@ class ShardedStepper:
             # out of the histogram by the bincount slice) instead of the
             # raw int32 delivered slice — 4x less transfer, and the
             # bucket fold rides the fused elementwise gather
-            def _bucket_take(a, c, b):
+            def hist_gather(a, c, b):
                 d = jnp.take(a, c, axis=1)
                 v = d - b[None, :]
                 ok = (d >= 0) & (v >= 0)
                 return jnp.where(ok, bucket_index_jnp(v),
                                  NB).astype(jnp.uint8)
 
-            self._take = jax.jit(_bucket_take)
+            self._take = jax.jit(hist_gather)
         self.rounds_dev = jax.device_put(np.int32(rounds), rep)
 
         if scan == "on":
@@ -402,6 +409,36 @@ class ShardedStepper:
                 base[app] = cw.slot_birth[app]
         return base
 
+    def _note_program(self, run, args) -> None:
+        """Remember a segment program and the shapes, dtypes and
+        shardings of its first dispatch's arguments (taken before the
+        dispatch, which donates the state).  A runner replaced by a
+        plain function (no ``jitted``) is not remembered."""
+        if run in self._programs or not hasattr(run, "jitted"):
+            return
+        jax = self._jax
+        self._programs[run] = (run.jitted, jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
+            args))
+
+    def program_texts(self) -> List[str]:
+        """The compiled HLO text of every segment program this stepper
+        has dispatched (``HloModule jit_segment_gated``, ...,
+        :data:`~repro.core.vecsim.shard.spanner.SEGMENT_PROGRAMS`).
+        Each instruction's ``op_name`` metadata carries the named phase
+        scope it belongs to (:data:`spanner.PHASE_SCOPES`), so a
+        profiler trace's per-instruction device time (``fusion.186``,
+        ``slice-start.107``) maps onto the body's phases.  Lowers and
+        compiles again (or loads from the compile cache): call it after
+        the run, never on the hot path."""
+        jax = self._jax
+        out = []
+        with jax.enable_x64(True):
+            for jitted, specs in self._programs.values():
+                out.append(jitted.lower(*specs).compile().as_text())
+        return out
+
     def _run_segment(self, lo: int, hi: int):
         """Dispatch segment ``[lo, hi)``; returns the (device) stats
         rows and, on the scanned path, the fused retirement aggregates.
@@ -419,6 +456,7 @@ class ShardedStepper:
                                                 self.rep)
                          for f in SlotSchedule.__dataclass_fields__
                          .values()}
+            self._note_program(self.runner, (self.state, sched_dev, ts_dev))
             rec.end()
             t1 = self._clock()
             rec.begin(sid["dispatch"])
@@ -438,25 +476,27 @@ class ShardedStepper:
                                     np.zeros((-self.w) % 8, bool)]),
                     bitorder="little")
                 ia_dev = self.stager._stage("__ia_pack", ia)
+                args = (self.state, tabs, ia_dev,
+                        {key: sched_dev[key]
+                         for key in ("bc_round", "bc_origin", "bc_slot",
+                                     "cr_round", "cr_pid")},
+                        sched_dev["ts"], origins_dev, self.rounds_dev)
+                self._note_program(frun, args)
                 rec.end()
                 t1 = self._clock()
                 rec.begin(sid["dispatch"])
-                self.state, stats, red = frun(
-                    self.state, tabs, ia_dev,
-                    {key: sched_dev[key]
-                     for key in ("bc_round", "bc_origin", "bc_slot",
-                                 "cr_round", "cr_pid")},
-                    sched_dev["ts"], origins_dev, self.rounds_dev)
+                self.state, stats, red = frun(*args)
                 rec.end()
             else:
                 sched_dev = self.stager.stage(lo, hi)
                 ts_dev = sched_dev.pop("ts")
+                args = (self.state, sched_dev, ts_dev, origins_dev,
+                        self.rounds_dev)
+                self._note_program(self.runner, args)
                 rec.end()
                 t1 = self._clock()
                 rec.begin(sid["dispatch"])
-                self.state, stats, red = self.runner(
-                    self.state, sched_dev, ts_dev, origins_dev,
-                    self.rounds_dev)
+                self.state, stats, red = self.runner(*args)
                 rec.end()
             self._apply_topo_events(lo, hi)
         if self.seg_profile is not None:
@@ -474,6 +514,7 @@ class ShardedStepper:
         if not len(cols):
             return
         cw = self.cw
+        rec, sid = self._rec, self._sid
         cnt, arrcnt, sumdel, bdone = red[0], red[1], red[2], red[7]
         ids = cw.slot_msg[cols]
         self.deliv_count[ids] = cnt[cols]
@@ -515,10 +556,15 @@ class ShardedStepper:
                 # sentinel: latency < 0, bucketed to NB and sliced off
                 base_p[: len(acols)] = np.where(bb >= 0, bb,
                                                 self.rounds + 1)
+                rec.begin(sid["retire.gather"])
                 idx = np.asarray(self._take(self.state[1], cols_p,
                                             base_p))
+                rec.end()
+                rec.begin(sid["retire.fold"])
                 counts = np.bincount(idx.ravel(), minlength=NB + 1)
                 self.obs.add_hist(counts[:NB].astype(np.int64))
+                rec.end()
+                rec.counter(self._cid["hist_bytes"], idx.nbytes)
         fl = self._flight
         if fl is not None and fl.open_count and app.any():
             # sampled provenance: gather only the sampled retiring
@@ -537,9 +583,12 @@ class ShardedStepper:
                 fl.on_retire(aidx[m], rows[: self.scn.n, : len(scols)],
                              self.t if t_now is None else t_now,
                              by_expiry[app][m])
+        rec.begin(sid["retire.apply"])
         self.state = self.apply_run(self.state, retire,
                                     retire & cw.slot_app, hung)
         cw.free_cols(cols)
+        rec.end()
+        rec.counter(self._cid["columns"], len(cols))
 
     def _retire(self, t_now: int, red_dev=None) -> int:
         """Retire columns from the fused segment aggregates (scanned
@@ -552,7 +601,9 @@ class ShardedStepper:
         if red_dev is None:
             red_dev = self.reduce_run(
                 self.state, self._column_origins(), self.rounds_dev)
+        self._rec.begin(self._sid["retire.pull"])
         red = tuple(np.asarray(x) for x in red_dev)
+        self._rec.end()
         (cnt, arrcnt, sumdel, alive, alivedel, blockcnt, refcnt,
          bdone) = red[:8]
         full_del = alivedel == int(alive)

@@ -63,9 +63,19 @@ __all__ = ["shard_span_runner", "shard_fast_span_runner",
            "shard_retire_kernels", "shard_hist_runner",
            "shard_column_gather",
            "resolve_shard_backend", "resolve_scan", "STATE_KEYS",
-           "INT16_LIMIT"]
+           "INT16_LIMIT", "SEGMENT_PROGRAMS", "PHASE_SCOPES"]
 
 STATE_KEYS = _STATE_KEYS
+
+# The jitted segment programs' names (their XLA modules are ``jit_<name>``)
+# and the ``jax.named_scope`` phases inside them, which the compiled
+# program carries in each instruction's ``op_name``: a profiler trace's
+# device time is read back by these names (``bench/``, DESIGN.md §2.10),
+# so they change only together with that reduction.
+SEGMENT_PROGRAMS = ("segment_gated", "segment_fast")
+PHASE_SCOPES = ("fold", "removals", "additions", "crashes", "broadcasts",
+                "deliveries", "pong", "flush_forward", "stats",
+                "retire_reduce", "convert")
 
 # int16 ceiling of the fast scanned body: arrival rounds live in int16
 # planes there, with this value standing in for INF.  The driver only
@@ -205,7 +215,8 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
         if deferred:
             # double-buffered frontier: the previous round's in-flight
             # ring contributions land now, before anything reads arr
-            arr = jnp.minimum(arr, pending)
+            with jax.named_scope("fold"):
+                arr = jnp.minimum(arr, pending)
         n_loc = arr.shape[0]
         width = arr.shape[1]
         me = jax.lax.axis_index("shard")
@@ -215,101 +226,110 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
 
         # -- 1. removals (owner-local; other shards drop) ---------------- #
         if sched["rm_round"].shape[0]:
-            sel = sched["rm_round"] == t
-            pl = sched["rm_p"].astype(jnp.int32) - off
-            p_ = jnp.where(sel & (pl >= 0) & (pl < n_loc), pl, n_loc)
-            k_ = sched["rm_k"]
-            active = active.at[p_, k_].set(False, mode="drop")
-            gate = gate.at[p_, k_].set(-1, mode="drop")
-            flush = flush.at[p_, k_].set(inf, mode="drop")
-            ping = ping.at[p_, k_].set(-1, mode="drop")
+            with jax.named_scope("removals"):
+                sel = sched["rm_round"] == t
+                pl = sched["rm_p"].astype(jnp.int32) - off
+                p_ = jnp.where(sel & (pl >= 0) & (pl < n_loc), pl, n_loc)
+                k_ = sched["rm_k"]
+                active = active.at[p_, k_].set(False, mode="drop")
+                gate = gate.at[p_, k_].set(-1, mode="drop")
+                flush = flush.at[p_, k_].set(inf, mode="drop")
+                ping = ping.at[p_, k_].set(-1, mode="drop")
 
         # -- 2. additions (+ Algorithm 2 gating, owner-local) ------------- #
         if sched["add_round"].shape[0]:
-            sel = sched["add_round"] == t
-            add_p, add_k = sched["add_p"], sched["add_k"]
-            add_slot = sched["add_slot"]
-            pl = add_p.astype(jnp.int32) - off
-            owned = (pl >= 0) & (pl < n_loc)
-            p_ = jnp.where(sel & owned, pl, n_loc)
-            adj = adj.at[p_, add_k].set(sched["add_q"], mode="drop")
-            delay = delay.at[p_, add_k].set(sched["add_delay"], mode="drop")
-            active = active.at[p_, add_k].set(True, mode="drop")
-            if pc:
-                safe_links = active & (gate < 0)
-                safe_cnt = safe_links.sum(axis=1)
-                pcl = jnp.clip(pl, 0, n_loc - 1)
-                own_slot_safe = safe_links[pcl, add_k]
-                other_safe = (safe_cnt[pcl]
-                              - own_slot_safe.astype(jnp.int32)) >= 1
-                if always_gate:
-                    want = other_safe
-                else:
-                    has_del = ever_del | ((delivered >= 0)
-                                          & is_app[None, :]).any(axis=1)
-                    want = other_safe & has_del[pcl]
-                want = want & ~crashed[pcl] & owned
-                gsel = sel & want
-                pg = jnp.where(gsel, pl, n_loc)
-                gate = gate.at[pg, add_k].set(t, mode="drop")
-                flush = flush.at[pg, add_k].set(inf, mode="drop")
-                ping = ping.at[pg, add_k].set(add_slot, mode="drop")
-                delivered = delivered.at[pg, add_slot].set(t, mode="drop")
-                csel = sel & ~want & owned
-                pc_ = jnp.where(csel, pl, n_loc)
-                gate = gate.at[pc_, add_k].set(-1, mode="drop")
-                flush = flush.at[pc_, add_k].set(inf, mode="drop")
-                ping = ping.at[pc_, add_k].set(-1, mode="drop")
+            with jax.named_scope("additions"):
+                sel = sched["add_round"] == t
+                add_p, add_k = sched["add_p"], sched["add_k"]
+                add_slot = sched["add_slot"]
+                pl = add_p.astype(jnp.int32) - off
+                owned = (pl >= 0) & (pl < n_loc)
+                p_ = jnp.where(sel & owned, pl, n_loc)
+                adj = adj.at[p_, add_k].set(sched["add_q"], mode="drop")
+                delay = delay.at[p_, add_k].set(sched["add_delay"],
+                                                mode="drop")
+                active = active.at[p_, add_k].set(True, mode="drop")
+                if pc:
+                    safe_links = active & (gate < 0)
+                    safe_cnt = safe_links.sum(axis=1)
+                    pcl = jnp.clip(pl, 0, n_loc - 1)
+                    own_slot_safe = safe_links[pcl, add_k]
+                    other_safe = (safe_cnt[pcl]
+                                  - own_slot_safe.astype(jnp.int32)) >= 1
+                    if always_gate:
+                        want = other_safe
+                    else:
+                        has_del = ever_del | ((delivered >= 0)
+                                              & is_app[None, :]).any(axis=1)
+                        want = other_safe & has_del[pcl]
+                    want = want & ~crashed[pcl] & owned
+                    gsel = sel & want
+                    pg = jnp.where(gsel, pl, n_loc)
+                    gate = gate.at[pg, add_k].set(t, mode="drop")
+                    flush = flush.at[pg, add_k].set(inf, mode="drop")
+                    ping = ping.at[pg, add_k].set(add_slot, mode="drop")
+                    delivered = delivered.at[pg, add_slot].set(t,
+                                                               mode="drop")
+                    csel = sel & ~want & owned
+                    pc_ = jnp.where(csel, pl, n_loc)
+                    gate = gate.at[pc_, add_k].set(-1, mode="drop")
+                    flush = flush.at[pc_, add_k].set(inf, mode="drop")
+                    ping = ping.at[pc_, add_k].set(-1, mode="drop")
 
         # -- 3. crashes (owner-local) ------------------------------------- #
         if sched["cr_round"].shape[0]:
-            sel = sched["cr_round"] == t
-            pl = sched["cr_pid"].astype(jnp.int32) - off
-            p_ = jnp.where(sel & (pl >= 0) & (pl < n_loc), pl, n_loc)
-            crashed = crashed.at[p_].set(True, mode="drop")
+            with jax.named_scope("crashes"):
+                sel = sched["cr_round"] == t
+                pl = sched["cr_pid"].astype(jnp.int32) - off
+                p_ = jnp.where(sel & (pl >= 0) & (pl < n_loc), pl, n_loc)
+                crashed = crashed.at[p_].set(True, mode="drop")
 
         # -- 4. broadcasts (owner-local) ---------------------------------- #
         if sched["bc_round"].shape[0]:
-            ol = sched["bc_origin"].astype(jnp.int32) - off
-            owned = (ol >= 0) & (ol < n_loc)
-            ocl = jnp.clip(ol, 0, n_loc - 1)
-            sel = (sched["bc_round"] == t) & owned & ~crashed[ocl]
-            o_ = jnp.where(sel, ol, n_loc)
-            delivered = delivered.at[o_, sched["bc_slot"]].max(t, mode="drop")
+            with jax.named_scope("broadcasts"):
+                ol = sched["bc_origin"].astype(jnp.int32) - off
+                owned = (ol >= 0) & (ol < n_loc)
+                ocl = jnp.clip(ol, 0, n_loc - 1)
+                sel = (sched["bc_round"] == t) & owned & ~crashed[ocl]
+                o_ = jnp.where(sel, ol, n_loc)
+                delivered = delivered.at[o_, sched["bc_slot"]].max(
+                    t, mode="drop")
 
         # -- 5. arrivals -> deliveries (element-wise, local) -------------- #
-        if pallas:
-            delivered, napp32, nping32 = kx.deliver_sweep(
-                arr, delivered, crashed, is_app, t)
-            napp = napp32.astype(jnp.int64)
-            nping = nping32.astype(jnp.int64)
-        else:
-            newly = (arr == t) & (delivered < 0) & ~crashed[:, None]
-            delivered = jnp.where(newly, t, delivered)
+        with jax.named_scope("deliveries"):
+            if pallas:
+                delivered, napp32, nping32 = kx.deliver_sweep(
+                    arr, delivered, crashed, is_app, t)
+                napp = napp32.astype(jnp.int64)
+                nping = nping32.astype(jnp.int64)
+            else:
+                newly = (arr == t) & (delivered < 0) & ~crashed[:, None]
+                delivered = jnp.where(newly, t, delivered)
 
         # -- 6. pong detection: the query ring ---------------------------- #
         if pc and gating:
-            # Exactly the monolithic read delivered[clip(adj), clip(ping)]
-            # for *every* slot, masked afterwards — the triples visit all
-            # D shards and come home with the answer filled in by the
-            # target row's owner.
-            q = jnp.clip(adj, 0, n_loc * d - 1).reshape(-1)
-            s = jnp.clip(ping, 0, width - 1).reshape(-1)
-            ans = jnp.full(q.shape, jnp.int32(-1))
-            for _hop in range(d):
-                ql = q - off
-                hit = (ql >= 0) & (ql < n_loc)
-                qcl = jnp.clip(ql, 0, n_loc - 1)
-                ans = jnp.where(hit, delivered[qcl, s], ans)
-                if d > 1:
-                    q = jax.lax.ppermute(q, "shard", perm)
-                    s = jax.lax.ppermute(s, "shard", perm)
-                    ans = jax.lax.ppermute(ans, "shard", perm)
-            tgt_del = ans.reshape(adj.shape)
-            fire = ((gate >= 0) & (flush == inf) & (ping >= 0)
-                    & (tgt_del >= 0) & ~crashed[:, None])
-            flush = jnp.where(fire, t + pong_delay, flush)
-            stats = stats.at[4].set(fire.sum().astype(jnp.int64))
+            with jax.named_scope("pong"):
+                # Exactly the monolithic read delivered[clip(adj),
+                # clip(ping)] for *every* slot, masked afterwards — the
+                # triples visit all D shards and come home with the
+                # answer filled in by the target row's owner.
+                q = jnp.clip(adj, 0, n_loc * d - 1).reshape(-1)
+                s = jnp.clip(ping, 0, width - 1).reshape(-1)
+                ans = jnp.full(q.shape, jnp.int32(-1))
+                for _hop in range(d):
+                    ql = q - off
+                    hit = (ql >= 0) & (ql < n_loc)
+                    qcl = jnp.clip(ql, 0, n_loc - 1)
+                    ans = jnp.where(hit, delivered[qcl, s], ans)
+                    if d > 1:
+                        q = jax.lax.ppermute(q, "shard", perm)
+                        s = jax.lax.ppermute(s, "shard", perm)
+                        ans = jax.lax.ppermute(ans, "shard", perm)
+                tgt_del = ans.reshape(adj.shape)
+                fire = ((gate >= 0) & (flush == inf) & (ping >= 0)
+                        & (tgt_del >= 0) & ~crashed[:, None])
+                flush = jnp.where(fire, t + pong_delay, flush)
+                stats = stats.at[4].set(fire.sum().astype(jnp.int64))
 
         # -- 7+8. flush + forward: the frontier exchange ------------------ #
         # Per link slot, the flush contributions (phase 7) and this
@@ -318,69 +338,76 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
         # over the same link, and scatter-min commutes, so the fusion is
         # exact.  A slot flushed this round becomes safe *before* the
         # forward pass, as in the monolithic body (gk_eff below).
-        if not pallas:
-            new_del = delivered == t
-            napp = (new_del & is_app[None, :]).sum(axis=1)
-            nping = (new_del & ~is_app[None, :]).sum(axis=1)
-            has_new = new_del.any(axis=1) & ~crashed
-        elig_cnt = jnp.zeros(n_loc, jnp.int64)
-        flush_sent = jnp.int64(0)
-        # deferred mode scatters into a fresh pending plane (folded into
-        # arr at the next round's entry); immediate mode scatters into
-        # arr directly, as the windowed reference does
-        dest = jnp.full_like(arr, inf) if deferred else arr
-        for kk in range(k):
-            gk = gate[:, kk]
-            dk = (t + delay[:, kk])[:, None].astype(jnp.int32)
-            if pc and gating:
-                do = (flush[:, kk] == t) & active[:, kk] & ~crashed
-                gk_eff = jnp.where(flush[:, kk] == t, -1, gk)
-            else:
-                do = jnp.zeros_like(crashed)
-                gk_eff = gk
-            ok = active[:, kk] & (gk_eff < 0) & (adj[:, kk] >= 0) & ~crashed
-            elig_cnt += ok.astype(jnp.int64)
-            if pallas:
-                # slot kernel: combined flush+forward contribution plane
-                # (a row with a delivery this round is never crashed, so
-                # the jax body's has_new conjunct is implied by new_del)
-                vals, win_cnt = kx.slot_frontier(
-                    delivered, gk, delay[:, kk], do, ok, is_app, t,
-                    gating=pc and gating)
-                flush_sent += win_cnt.astype(jnp.int64)
-            else:
+        with jax.named_scope("flush_forward"):
+            if not pallas:
+                new_del = delivered == t
+                napp = (new_del & is_app[None, :]).sum(axis=1)
+                nping = (new_del & ~is_app[None, :]).sum(axis=1)
+                has_new = new_del.any(axis=1) & ~crashed
+            elig_cnt = jnp.zeros(n_loc, jnp.int64)
+            flush_sent = jnp.int64(0)
+            # deferred mode scatters into a fresh pending plane (folded
+            # into arr at the next round's entry); immediate mode
+            # scatters into arr directly, as the windowed reference does
+            dest = jnp.full_like(arr, inf) if deferred else arr
+            for kk in range(k):
+                gk = gate[:, kk]
+                dk = (t + delay[:, kk])[:, None].astype(jnp.int32)
                 if pc and gating:
-                    win = ((delivered >= gk[:, None]) & (delivered < t)
-                           & do[:, None] & is_app[None, :])
-                    flush_sent += win.sum().astype(jnp.int64)
-                fwd = ok & has_new
-                vals = jnp.where(new_del & fwd[:, None], dk, inf)
-                if pc and gating:
-                    vals = jnp.minimum(vals, jnp.where(win, dk, inf))
-            tgt = adj[:, kk].astype(jnp.int32)
-            for hop in range(d):
-                if pallas:
-                    dest = kx.ring_apply(dest, vals, tgt, off)
+                    do = (flush[:, kk] == t) & active[:, kk] & ~crashed
+                    gk_eff = jnp.where(flush[:, kk] == t, -1, gk)
                 else:
-                    tl = tgt - off
-                    rows = jnp.where((tl >= 0) & (tl < n_loc), tl, n_loc)
-                    dest = dest.at[rows, :].min(vals, mode="drop")
-                if hop < d - 1:
-                    vals = jax.lax.ppermute(vals, "shard", perm)
-                    tgt = jax.lax.ppermute(tgt, "shard", perm)
-        if not deferred:
-            arr = dest
-        if pc and gating:
-            cleared = flush == t
-            gate = jnp.where(cleared, -1, gate)
-            ping = jnp.where(cleared, -1, ping)
-            flush = jnp.where(cleared, inf, flush)
-        stats = stats.at[0].set(napp.sum().astype(jnp.int64))
-        stats = stats.at[1].set((napp.astype(jnp.int64) * elig_cnt).sum())
-        stats = stats.at[2].set((nping.astype(jnp.int64) * elig_cnt).sum())
-        stats = stats.at[3].set(flush_sent)
-        stats = stats.at[5].set((gate >= 0).sum().astype(jnp.int64))
-        stats = jax.lax.psum(stats, "shard")
+                    do = jnp.zeros_like(crashed)
+                    gk_eff = gk
+                ok = (active[:, kk] & (gk_eff < 0) & (adj[:, kk] >= 0)
+                      & ~crashed)
+                elig_cnt += ok.astype(jnp.int64)
+                if pallas:
+                    # slot kernel: combined flush+forward contribution
+                    # plane (a row with a delivery this round is never
+                    # crashed, so the jax body's has_new conjunct is
+                    # implied by new_del)
+                    vals, win_cnt = kx.slot_frontier(
+                        delivered, gk, delay[:, kk], do, ok, is_app, t,
+                        gating=pc and gating)
+                    flush_sent += win_cnt.astype(jnp.int64)
+                else:
+                    if pc and gating:
+                        win = ((delivered >= gk[:, None]) & (delivered < t)
+                               & do[:, None] & is_app[None, :])
+                        flush_sent += win.sum().astype(jnp.int64)
+                    fwd = ok & has_new
+                    vals = jnp.where(new_del & fwd[:, None], dk, inf)
+                    if pc and gating:
+                        vals = jnp.minimum(vals, jnp.where(win, dk, inf))
+                tgt = adj[:, kk].astype(jnp.int32)
+                for hop in range(d):
+                    if pallas:
+                        dest = kx.ring_apply(dest, vals, tgt, off)
+                    else:
+                        tl = tgt - off
+                        rows = jnp.where((tl >= 0) & (tl < n_loc), tl,
+                                         n_loc)
+                        dest = dest.at[rows, :].min(vals, mode="drop")
+                    if hop < d - 1:
+                        vals = jax.lax.ppermute(vals, "shard", perm)
+                        tgt = jax.lax.ppermute(tgt, "shard", perm)
+            if not deferred:
+                arr = dest
+            if pc and gating:
+                cleared = flush == t
+                gate = jnp.where(cleared, -1, gate)
+                ping = jnp.where(cleared, -1, ping)
+                flush = jnp.where(cleared, inf, flush)
+        with jax.named_scope("stats"):
+            stats = stats.at[0].set(napp.sum().astype(jnp.int64))
+            stats = stats.at[1].set(
+                (napp.astype(jnp.int64) * elig_cnt).sum())
+            stats = stats.at[2].set(
+                (nping.astype(jnp.int64) * elig_cnt).sum())
+            stats = stats.at[3].set(flush_sent)
+            stats = stats.at[5].set((gate >= 0).sum().astype(jnp.int64))
+            stats = jax.lax.psum(stats, "shard")
 
         out = (arr, delivered, adj, delay, active, gate, flush, ping,
                crashed, ever_del)
@@ -405,7 +432,7 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 lambda c: (c, jnp.zeros(len(SERIES_FIELDS), jnp.int64)),
                 carry)
 
-        def span(state, sched, ts, origins, rounds):
+        def segment_gated(state, sched, ts, origins, rounds):
             is_app = sched["is_app"]
             events = {key: v for key, v in sched.items() if key != "is_app"}
             pending0 = jnp.full_like(state[0], inf)
@@ -420,20 +447,25 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 body, (tuple(state), pending0), (ts, events))
             # residual fold: the last round's in-flight frontier (padding
             # rounds skip real_step, so pending survives to here intact)
-            state = (jnp.minimum(state[0], pending),) + tuple(state[1:])
+            with jax.named_scope("fold"):
+                state = ((jnp.minimum(state[0], pending),)
+                         + tuple(state[1:]))
             # fused retirement reduce (DESIGN.md §2.8): the per-column
             # aggregates the driver's retire() consumes come out of the
             # same dispatch as the segment itself, while the planes are
             # still hot — shared definition with shard_retire_kernels
-            me = jax.lax.axis_index("shard")
-            off = (me * state[0].shape[0]).astype(jnp.int32)
-            red = tuple(jax.lax.psum(x, "shard")
-                        for x in _column_partials(state, origins,
-                                                  rounds, off))
+            with jax.named_scope("retire_reduce"):
+                me = jax.lax.axis_index("shard")
+                off = (me * state[0].shape[0]).astype(jnp.int32)
+                red = tuple(jax.lax.psum(x, "shard")
+                            for x in _column_partials(state, origins,
+                                                      rounds, off))
             return state, stats, red
+        span = segment_gated
     else:
-        def span(state, sched, ts):
+        def segment_stepped(state, sched, ts):
             return jax.lax.scan(lambda c, t: step(sched, c, t), state, ts)
+        span = segment_stepped
 
     # check_vma=False: lax.cond trips shard_map's replication checker
     # (jax-ml/jax known limitation); the stats output really is
@@ -512,7 +544,7 @@ def shard_fast_span_runner(n_devices: int, classes_sig: tuple):
     perm = _shift(d)
     classes = tuple(classes_sig)
 
-    def span(state, tabs, ia_pack, sched, ts, origins, rounds):
+    def segment_fast(state, tabs, ia_pack, sched, ts, origins, rounds):
         (arr, delivered, adj, delay, active, gate, flush, ping,
          crashed, ever_del) = state
         n_loc, width = arr.shape
@@ -521,34 +553,39 @@ def shard_fast_span_runner(n_devices: int, classes_sig: tuple):
         off = (me * n_loc).astype(jnp.int32)
         n_glob = n_loc * d
 
-        arr16 = jnp.where(arr >= inf, lim16, arr.astype(jnp.int16))
-        del16 = delivered.astype(jnp.int16)
+        with jax.named_scope("convert"):
+            arr16 = jnp.where(arr >= inf, lim16, arr.astype(jnp.int16))
+            del16 = delivered.astype(jnp.int16)
 
         # Receiver-side gather positions into the all-gathered frontier,
         # hoisted out of the scan: ring hop j delivers the block owned
         # by shard (me - j) % d, so global source row s = blk*n_loc + r
         # sits at ((me - blk) % d) * n_loc + r.  "No source" entries
         # point past the end; the gather fills them with zero bytes.
-        poss = []
-        for ci, (dl, b) in enumerate(classes):
-            ip = tabs[ci]
-            blk = ip // n_loc
-            pos = ((me - blk) % d) * n_loc + (ip - blk * n_loc)
-            poss.append(jnp.where(ip >= n_glob, n_glob,
-                                  pos).astype(jnp.int32))
+        with jax.named_scope("flush_forward"):
+            poss = []
+            for ci, (dl, b) in enumerate(classes):
+                ip = tabs[ci]
+                blk = ip // n_loc
+                pos = ((me - blk) % d) * n_loc + (ip - blk * n_loc)
+                poss.append(jnp.where(ip >= n_glob, n_glob,
+                                      pos).astype(jnp.int32))
         # per-row eligible-link count: static over the segment except
         # for crashes, which zero the whole row (matching the reference
         # body's per-slot `ok &= ~crashed`)
-        linkcnt = (active & (adj >= 0)).sum(axis=1).astype(jnp.int64)
-        gated = (gate >= 0).sum().astype(jnp.int64)
+        with jax.named_scope("stats"):
+            linkcnt = (active & (adj >= 0)).sum(axis=1).astype(jnp.int64)
+            gated = (gate >= 0).sum().astype(jnp.int64)
 
         def fold(arr16, pend, tprev):
             # deferred packed frontier: contributions gathered during
             # round tprev arrive with value tprev + delay
-            for ci, (dl, b) in enumerate(classes):
-                pb = unpack_columns(pend[ci], width)
-                arr16 = jnp.where(
-                    pb, jnp.minimum(arr16, tprev + jnp.int16(dl)), arr16)
+            with jax.named_scope("fold"):
+                for ci, (dl, b) in enumerate(classes):
+                    pb = unpack_columns(pend[ci], width)
+                    arr16 = jnp.where(
+                        pb, jnp.minimum(arr16, tprev + jnp.int16(dl)),
+                        arr16)
             return arr16
 
         def body(carry, x):
@@ -559,48 +596,56 @@ def shard_fast_span_runner(n_devices: int, classes_sig: tuple):
             # crashes / broadcasts (owner-local; sentinel rounds in the
             # stacked rows never match a real t)
             if cr_r.shape[0]:
-                pl = cr_p.astype(jnp.int32) - off
-                p_ = jnp.where((cr_r == t) & (pl >= 0) & (pl < n_loc),
-                               pl, n_loc)
-                crs = crs.at[p_].set(True, mode="drop")
+                with jax.named_scope("crashes"):
+                    pl = cr_p.astype(jnp.int32) - off
+                    p_ = jnp.where((cr_r == t) & (pl >= 0) & (pl < n_loc),
+                                   pl, n_loc)
+                    crs = crs.at[p_].set(True, mode="drop")
             if bc_r.shape[0]:
-                ol = bc_o.astype(jnp.int32) - off
-                owned = (ol >= 0) & (ol < n_loc)
-                ocl = jnp.clip(ol, 0, n_loc - 1)
-                sel = (bc_r == t) & owned & ~crs[ocl]
-                o_ = jnp.where(sel, ol, n_loc)
-                del16 = del16.at[o_, bc_s].max(t16, mode="drop")
+                with jax.named_scope("broadcasts"):
+                    ol = bc_o.astype(jnp.int32) - off
+                    owned = (ol >= 0) & (ol < n_loc)
+                    ocl = jnp.clip(ol, 0, n_loc - 1)
+                    sel = (bc_r == t) & owned & ~crs[ocl]
+                    o_ = jnp.where(sel, ol, n_loc)
+                    del16 = del16.at[o_, bc_s].max(t16, mode="drop")
             # arrivals -> deliveries (padding rounds: t16 < 0 matches
             # no arr/delivered value, so everything below is a no-op)
-            newly = (arr16 == t16) & (del16 < 0) & ~crs[:, None]
-            del16 = jnp.where(newly, t16, del16)
-            # pack this round's frontier once; the barrier pins a single
-            # materialization (XLA otherwise re-runs the producer chain
-            # per consumer: stats, ring, and gather)
-            g = jax.lax.optimization_barrier(pack_columns(del16 == t16))
-            rowsum = jnp.sum(popcount_bytes(g), axis=1, dtype=jnp.int64)
-            napp = jnp.sum(popcount_bytes(g & ia_pack[None, :]), axis=1,
-                           dtype=jnp.int64)
-            elig = jnp.where(crs, 0, linkcnt)
-            z = jnp.int64(0)
-            stats = jnp.stack([
-                napp.sum(), (napp * elig).sum(),
-                ((rowsum - napp) * elig).sum(), z, z,
-                jnp.where(t >= 0, gated, z)])
+            with jax.named_scope("deliveries"):
+                newly = (arr16 == t16) & (del16 < 0) & ~crs[:, None]
+                del16 = jnp.where(newly, t16, del16)
+                # pack this round's frontier once; the barrier pins a
+                # single materialization (XLA otherwise re-runs the
+                # producer chain per consumer: stats, ring, and gather)
+                g = jax.lax.optimization_barrier(
+                    pack_columns(del16 == t16))
+            with jax.named_scope("stats"):
+                rowsum = jnp.sum(popcount_bytes(g), axis=1,
+                                 dtype=jnp.int64)
+                napp = jnp.sum(popcount_bytes(g & ia_pack[None, :]),
+                               axis=1, dtype=jnp.int64)
+                elig = jnp.where(crs, 0, linkcnt)
+                z = jnp.int64(0)
+                stats = jnp.stack([
+                    napp.sum(), (napp * elig).sum(),
+                    ((rowsum - napp) * elig).sum(), z, z,
+                    jnp.where(t >= 0, gated, z)])
             # all-gather the packed frontier around the ring
-            blocks = [g]
-            for _hop in range(d - 1):
-                blocks.append(jax.lax.ppermute(blocks[-1], "shard", perm))
-            gg = jnp.concatenate(blocks, axis=0) if d > 1 else g
-            pend_new = []
-            for ci, (dl, b) in enumerate(classes):
-                pos = poss[ci]
-                acc = jnp.take(gg, pos[:, 0], axis=0, mode="fill",
-                               fill_value=0)
-                for col in range(1, b):
-                    acc = acc | jnp.take(gg, pos[:, col], axis=0,
-                                         mode="fill", fill_value=0)
-                pend_new.append(acc)
+            with jax.named_scope("flush_forward"):
+                blocks = [g]
+                for _hop in range(d - 1):
+                    blocks.append(jax.lax.ppermute(blocks[-1], "shard",
+                                                   perm))
+                gg = jnp.concatenate(blocks, axis=0) if d > 1 else g
+                pend_new = []
+                for ci, (dl, b) in enumerate(classes):
+                    pos = poss[ci]
+                    acc = jnp.take(gg, pos[:, 0], axis=0, mode="fill",
+                                   fill_value=0)
+                    for col in range(1, b):
+                        acc = acc | jnp.take(gg, pos[:, col], axis=0,
+                                             mode="fill", fill_value=0)
+                    pend_new.append(acc)
             return (arr16, del16, crs, tuple(pend_new), t16), stats
 
         pend0 = tuple(jnp.zeros((n_loc, wp), jnp.uint8) for _ in classes)
@@ -610,19 +655,23 @@ def shard_fast_span_runner(n_devices: int, classes_sig: tuple):
         (arr16, del16, crashed, pend, tprev), stats = jax.lax.scan(
             body, carry0, xs)
         arr16 = fold(arr16, pend, tprev)
-        stats = jax.lax.psum(stats, "shard")
-        arr = jnp.where(arr16 >= lim16, inf, arr16.astype(jnp.int32))
-        delivered = del16.astype(jnp.int32)
+        with jax.named_scope("stats"):
+            stats = jax.lax.psum(stats, "shard")
+        with jax.named_scope("convert"):
+            arr = jnp.where(arr16 >= lim16, inf, arr16.astype(jnp.int32))
+            delivered = del16.astype(jnp.int32)
         state = (arr, delivered, adj, delay, active, gate, flush, ping,
                  crashed, ever_del)
         # fused retirement reduce on the widened exit state — same
         # shared reduction as the generic scanned body (DESIGN.md §2.8)
-        red = tuple(jax.lax.psum(x, "shard")
-                    for x in _column_partials(state, origins, rounds, off))
+        with jax.named_scope("retire_reduce"):
+            red = tuple(jax.lax.psum(x, "shard")
+                        for x in _column_partials(state, origins, rounds,
+                                                  off))
         return state, stats, red
 
     _run = jax.jit(jax.shard_map(
-        span, mesh=mesh,
+        segment_fast, mesh=mesh,
         in_specs=(P("shard"), P("shard"), P(), P(), P(), P(), P()),
         out_specs=(P("shard"), P(), P()),
         check_vma=False),
@@ -653,7 +702,7 @@ def shard_retire_kernels(n_devices: int):
     mesh = shard_mesh(n_devices)
     inf = jnp.int32(INF)
 
-    def reduce_fn(state, origins, rounds):
+    def retire_reduce(state, origins, rounds):
         n_loc = state[0].shape[0]
         me = jax.lax.axis_index("shard")
         off = (me * n_loc).astype(jnp.int32)
@@ -661,11 +710,11 @@ def shard_retire_kernels(n_devices: int):
         return tuple(jax.lax.psum(x, "shard") for x in out)
 
     _reduce = jax.jit(jax.shard_map(
-        reduce_fn, mesh=mesh,
+        retire_reduce, mesh=mesh,
         in_specs=(P("shard"), P(), P()),
         out_specs=P()))
 
-    def apply_fn(state, retire, app_retire, hung):
+    def retire_apply(state, retire, app_retire, hung):
         (arr, delivered, adj, delay, active, gate, flush, ping,
          crashed, ever_del) = state
         w = arr.shape[1]
@@ -685,7 +734,7 @@ def shard_retire_kernels(n_devices: int):
                 crashed, ever_del)
 
     _apply = jax.jit(jax.shard_map(
-        apply_fn, mesh=mesh,
+        retire_apply, mesh=mesh,
         in_specs=(P("shard"), P(), P(), P()),
         out_specs=P("shard")))
 
@@ -697,6 +746,8 @@ def shard_retire_kernels(n_devices: int):
         with jax.enable_x64(True):
             return _apply(state, retire, app_retire, hung)
 
+    reduce_run.jitted = _reduce
+    apply_run.jitted = _apply
     return reduce_run, apply_run
 
 
@@ -769,12 +820,12 @@ def shard_column_gather():
     (``repro.obs.flight``): pull the delivered-plane rows of only the
     (power-of-two padded) sampled retiring columns before ``apply_run``
     recycles them.  Same O(sample) transfer pattern as the latency
-    histogram's ``_bucket_take``, minus the bucketing — provenance
+    histogram's ``hist_gather``, minus the bucketing — provenance
     wants the raw per-receiver delivery rounds."""
     import jax
     import jax.numpy as jnp
 
-    def _take(a, c):
+    def column_gather(a, c):
         return jnp.take(a, c, axis=1)
 
-    return jax.jit(_take)
+    return jax.jit(column_gather)

@@ -12,6 +12,19 @@ phase and ``instant``/``counter`` for point events.  Design constraints
 * a no-op twin — ``NULL_RECORDER`` has the same surface with empty
   bodies, so instrumented code never branches on "is telemetry on".
 
+A recording recorder also writes each span into the JAX profiler: every
+``begin`` opens a ``jax.profiler.TraceAnnotation`` of the span's name
+and the matching ``end`` closes it, so a ``jax.profiler`` trace taken
+of a run holds the program's spans on the trace's own host clock, beside
+the device programs they wait on (jax is imported only when a recording
+recorder is built).  Instants and counters stay in the recorder.
+
+The span names (``tick.*``, ``segment.*``, ``stager.upload``,
+``backpressure``, the ``retire.*`` counters) and the names of the
+jitted device programs and their ``jax.named_scope`` phases are a
+contract: ``bench/`` reads them by name (DESIGN.md §2.10), so a rename
+updates the benchmark's readers in the same change.
+
 Event kinds map straight onto the Chrome trace-event phases the sink
 emits: span (``"X"`` complete event), instant (``"i"``), counter
 (``"C"``).
@@ -45,6 +58,12 @@ class SpanRecorder:
     enabled = True
 
     def __init__(self, capacity: int = 65536):
+        if self.enabled:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        # profiler annotations of the open spans, innermost last; unlike
+        # the fixed-depth stack below it holds spans past _MAX_DEPTH too
+        self._open: list = []
         self.capacity = int(capacity)
         self.kind = np.zeros(self.capacity, np.int8)
         self.name_id = np.zeros(self.capacity, np.int32)
@@ -75,6 +94,9 @@ class SpanRecorder:
         return nid
 
     def begin(self, name_id: int) -> None:
+        ann = self._annotation(self._names[name_id])
+        ann.__enter__()
+        self._open.append(ann)
         d = self._depth
         if d < _MAX_DEPTH:
             self._stack_name[d] = name_id
@@ -86,13 +108,14 @@ class SpanRecorder:
         if d < 0:
             return
         self._depth = d
+        t1 = time.monotonic_ns()
+        self._open.pop().__exit__(None, None, None)
         if d >= _MAX_DEPTH:
             return
         i = self.n
         if i >= self.capacity:
             self.dropped += 1
             return
-        t1 = time.monotonic_ns()
         self.kind[i] = _KIND_SPAN
         self.name_id[i] = self._stack_name[d]
         self.t0_ns[i] = self._stack_t0[d]
