@@ -49,8 +49,8 @@ from .mesh import inverse_tables, pad_rows, resolve_devices, shard_mesh, \
     topology_digest
 from .spanner import (INT16_LIMIT, STATE_KEYS, resolve_scan,
                       resolve_shard_backend, shard_column_gather,
-                      shard_fast_span_runner, shard_retire_kernels,
-                      shard_span_runner)
+                      shard_fast_span_runner, shard_hist_runner,
+                      shard_retire_kernels, shard_span_runner)
 
 __all__ = ["ShardedRunResult", "ShardedStepper", "execute_sharded"]
 
@@ -68,6 +68,13 @@ class ShardedRunResult(WindowedRunResult):
     n_devices: int = 1
     scan: str = "off"
     seg_profile: Optional[List[dict]] = field(default=None, repr=False)
+
+
+def _folds_on_device(mesh) -> bool:
+    """Whether the latency histogram folds on the mesh's devices:
+    yes on an accelerator mesh, where pulling a bucket plane idles the
+    chip; no on a CPU mesh, where the host fold is cheaper."""
+    return mesh.devices.flat[0].platform != "cpu"
 
 
 def _padded_state(scn: VecScenario, w: int, n_pad: int) -> Dict[str, np.ndarray]:
@@ -265,11 +272,17 @@ class ShardedStepper:
 
         # telemetry (repro.obs): the segment bodies are telemetry-free
         # either way — the latency histogram is a separate per-retirement
-        # dispatch over only the retiring columns (shard_hist_runner), so
-        # both arms of the CI overhead gate lean on the same traced
-        # segment program
+        # dispatch that counts only the retiring columns, so both arms
+        # of the CI overhead gate lean on the same traced segment
+        # program.  On an accelerator mesh the histogram folds on the
+        # device (shard_hist_runner) and the host pulls only the (NB,)
+        # totals: a pulled bucket plane would idle the chip through the
+        # host's bincount.  On a CPU mesh the host folds the pulled
+        # uint8 bucket plane (hist_gather + bincount), cheaper there
+        # than the shard_map reduce
         self.obs = obs
         self.hist = obs is not None and obs.histograms
+        self.fold_on_device = self.hist and _folds_on_device(self.mesh)
         self._rec = obs.spans if obs is not None else NULL_RECORDER
         self._sid = {name: self._rec.name(f"segment.{name}")
                      for name in ("stage", "dispatch", "block", "retire",
@@ -297,11 +310,13 @@ class ShardedStepper:
 
             from ....obs.hist import bucket_index_jnp
 
-            # jitted retiring-column gather + on-device log bucketing:
-            # the host pulls one uint8 index plane (NB = invalid, kept
-            # out of the histogram by the bincount slice) instead of the
-            # raw int32 delivered slice — 4x less transfer, and the
-            # bucket fold rides the fused elementwise gather
+            # the host fold's jitted retiring-column gather + on-device
+            # log bucketing: the host pulls one uint8 index plane (NB =
+            # invalid, kept out of the histogram by the bincount slice)
+            # instead of the raw int32 delivered slice — 4x less
+            # transfer, and the bucket fold rides the fused elementwise
+            # gather.  Built on every mesh (bench/harness.py warms its
+            # widths); only CPU meshes dispatch it
             def hist_gather(a, c, b):
                 d = jnp.take(a, c, axis=1)
                 v = d - b[None, :]
@@ -310,6 +325,12 @@ class ShardedStepper:
                                  NB).astype(jnp.uint8)
 
             self._take = jax.jit(hist_gather)
+        if self.fold_on_device:
+            # one shape per window: compile it now, with the segment
+            # programs' set-up, never at a served tick's retirement
+            self._fold = shard_hist_runner(d)
+            self._fold(self.state[1],
+                       np.full(w, -1, np.int32)).block_until_ready()
         self.rounds_dev = jax.device_put(np.int32(rounds), rep)
 
         if scan == "on":
@@ -536,35 +557,13 @@ class ShardedStepper:
             if self.hist:
                 # latency histogram over only the retiring app columns,
                 # read while their delivered plane is still intact
-                # (apply_run below recycles it): one jitted gather of
-                # the retiring slice — padded to a few power-of-two
-                # widths so it compiles a handful of shapes — with the
-                # log bucketing fused on device, so the host pulls a
-                # uint8 bucket-index plane and folds it with a single
-                # bincount.  Cheap enough that the CI overhead gate's
-                # enabled arm holds on a CPU mesh; shard_hist_runner is
-                # the fully on-device twin for accelerator meshes
-                # (parity-tested)
+                # (apply_run below recycles it); a column whose base is
+                # negative (no reference round) counts nowhere
                 base = self._column_base()
-                r = min(max(8, 1 << (len(acols) - 1).bit_length()),
-                        max(self.w, 8))
-                cols_p = np.zeros(r, np.int32)
-                base_p = np.full(r, self.rounds + 1, np.int32)
-                cols_p[: len(acols)] = acols
-                bb = base[acols]
-                # negative base (no reference round) joins the padding
-                # sentinel: latency < 0, bucketed to NB and sliced off
-                base_p[: len(acols)] = np.where(bb >= 0, bb,
-                                                self.rounds + 1)
-                rec.begin(sid["retire.gather"])
-                idx = np.asarray(self._take(self.state[1], cols_p,
-                                            base_p))
-                rec.end()
-                rec.begin(sid["retire.fold"])
-                counts = np.bincount(idx.ravel(), minlength=NB + 1)
-                self.obs.add_hist(counts[:NB].astype(np.int64))
-                rec.end()
-                rec.counter(self._cid["hist_bytes"], idx.nbytes)
+                if self.fold_on_device:
+                    self._device_fold(acols, base[acols])
+                else:
+                    self._host_fold(acols, base[acols])
         fl = self._flight
         if fl is not None and fl.open_count and app.any():
             # sampled provenance: gather only the sampled retiring
@@ -589,6 +588,49 @@ class ShardedStepper:
         cw.free_cols(cols)
         rec.end()
         rec.counter(self._cid["columns"], len(cols))
+
+    def _device_fold(self, acols: np.ndarray, bb: np.ndarray) -> None:
+        """Fold the latency histogram of the retiring columns on the
+        mesh (``shard_hist_runner``) and pull only its ``(NB,)`` int64
+        totals: one dispatch over the whole plane, where the columns
+        not retiring carry base -1."""
+        rec, sid = self._rec, self._sid
+        base_w = np.full(self.w, -1, np.int32)
+        base_w[acols] = bb
+        rec.begin(sid["retire.gather"])
+        tot = self._fold(self.state[1], base_w)
+        rec.end()
+        rec.begin(sid["retire.fold"])
+        counts = np.asarray(tot)
+        self.obs.add_hist(counts)
+        rec.end()
+        rec.counter(self._cid["hist_bytes"], counts.nbytes)
+
+    def _host_fold(self, acols: np.ndarray, bb: np.ndarray) -> None:
+        """Fold the latency histogram of the retiring columns on the
+        host: one jitted gather of the retiring slice — padded to a few
+        power-of-two widths so it compiles a handful of shapes — with
+        the log bucketing fused on device, so the host pulls a uint8
+        bucket-index plane and folds it with a single bincount.  Cheap
+        enough that the CI overhead gate's enabled arm holds on a CPU
+        mesh."""
+        rec, sid = self._rec, self._sid
+        r = min(max(8, 1 << (len(acols) - 1).bit_length()),
+                max(self.w, 8))
+        cols_p = np.zeros(r, np.int32)
+        base_p = np.full(r, self.rounds + 1, np.int32)
+        cols_p[: len(acols)] = acols
+        # negative base joins the padding sentinel: latency < 0,
+        # bucketed to NB and sliced off
+        base_p[: len(acols)] = np.where(bb >= 0, bb, self.rounds + 1)
+        rec.begin(sid["retire.gather"])
+        idx = np.asarray(self._take(self.state[1], cols_p, base_p))
+        rec.end()
+        rec.begin(sid["retire.fold"])
+        counts = np.bincount(idx.ravel(), minlength=NB + 1)
+        self.obs.add_hist(counts[:NB].astype(np.int64))
+        rec.end()
+        rec.counter(self._cid["hist_bytes"], idx.nbytes)
 
     def _retire(self, t_now: int, red_dev=None) -> int:
         """Retire columns from the fused segment aggregates (scanned

@@ -129,9 +129,10 @@ def _column_partials(state, origins, rounds, off):
     *before* the mesh ``psum``; callers psum it across shards.
 
     Deliberately telemetry-free: the delivery-latency histogram is a
-    separate retirement-time dispatch (:func:`shard_hist_runner`) over
-    only the retiring columns, so enabling telemetry never re-traces or
-    slows the segment bodies (DESIGN.md §2.10).
+    separate retirement-time dispatch that counts only the retiring
+    columns (:func:`shard_hist_runner` on accelerator meshes), so
+    enabling telemetry never re-traces or slows the segment bodies
+    (DESIGN.md §2.10).
     """
     import jax.numpy as jnp
 
@@ -754,26 +755,29 @@ def shard_retire_kernels(n_devices: int):
 @functools.lru_cache(maxsize=None)
 def shard_hist_runner(n_devices: int):
     """On-device retirement-time delivery-latency histogram
-    (``repro.obs.hist`` bucket contract): gather the retiring columns
-    out of the sharded ``delivered`` plane, bucket each valid
-    delivery's ``delivered - base`` latency on device, and psum the
-    ``(NB,)`` totals across the mesh.  Columns padded with
-    ``base = -1`` contribute nothing, mirroring ``hist_np``'s
-    negative-value mask.
+    (``repro.obs.hist`` bucket contract): bucket each valid delivery's
+    ``delivered - base`` latency in the sharded ``delivered`` plane,
+    column by column, and psum the ``(NB,)`` totals across the mesh.
+    ``base`` is one reference round per window column; a column with
+    ``base < 0`` (not retiring, not an app column, or no reference
+    round) contributes nothing, and neither does a negative latency,
+    mirroring ``hist_np``'s ``v >= 0`` mask.
 
-    This is the fully on-device twin of the sharded driver's fold
-    (device bucket indices + host bincount): both run once per
-    retirement batch over only the retiring columns — O(N x messages)
-    work for the whole run, segment bodies telemetry-free — and are
-    byte-identical (``tests/test_obs.py`` parity-checks them).  The
-    driver pulls the uint8 index plane because on a CPU mesh the
-    shard_map reduce costs more than the transfer it saves; this
-    runner is the shape the fold takes when the delivered plane lives
-    on a real accelerator mesh and any host pull is the expensive
-    direction.
+    The sharded driver folds with this runner on accelerator meshes and
+    pulls only the ``(NB,)`` totals; on CPU meshes it keeps the host
+    fold (``hist_gather``'s uint8 bucket plane + ``np.bincount``),
+    where this shard_map reduce costs more than the transfer it saves.
+    The two are byte-identical (``tests/test_obs.py``,
+    ``tests/test_obs_trace.py`` and ``tests/test_vecsim_shard.py``
+    parity-check them).
 
-    The bucketing is the cumulative-count formulation: NB integer
-    ``value < upper_bound`` comparisons and a diff, byte-identical to
+    The program reads the whole plane once, with no gather: one
+    multi-output reduce over the row axis gives every column's count
+    below each bucket edge (NB + 1 int32 sums of ``(W,)``, exact while
+    a shard holds fewer than 2**31 rows), and a diff of their int64
+    column totals gives the buckets.  A single shape per window, so it
+    compiles once.  The bucketing is the cumulative-count formulation:
+    integer ``value < upper_bound`` comparisons, byte-identical to
     ``bucket_index_np`` + bincount because both are pure integer
     threshold counts over the same bucket edges.
     """
@@ -789,28 +793,28 @@ def shard_hist_runner(n_devices: int):
     hi = [k + 1 for k in range(16)] + [1 << k for k in range(5, 20)]
     assert len(hi) + 1 == NB
 
-    def hist_fn(delivered, cols, base):
-        d = delivered[:, cols]
-        valid = (d >= 0) & (base >= 0)[None, :]
-        v = jnp.where(valid, d - base[None, :], -1)
-        # cumulative counts at each bucket's upper bound; prepend the
-        # (normally zero) count of negative latencies so they fall out
-        # of bucket 0 exactly as hist_np's v >= 0 mask drops them
-        cum = jnp.stack([(valid & (v < 0)).sum().astype(jnp.int64)]
-                        + [(valid & (v < h)).sum().astype(jnp.int64)
+    def hist_fold(delivered, base):
+        valid = (delivered >= 0) & (base >= 0)[None, :]
+        v = jnp.where(valid, delivered - base[None, :], -1)
+        # per-column counts below each edge; the first (normally zero)
+        # counts negative latencies so they fall out of bucket 0
+        cum = jnp.stack([(valid & (v < 0)).sum(0, dtype=jnp.int32)]
+                        + [(valid & (v < h)).sum(0, dtype=jnp.int32)
                            for h in hi]
-                        + [valid.sum().astype(jnp.int64)])
-        return jax.lax.psum(jnp.diff(cum), "shard")
+                        + [valid.sum(0, dtype=jnp.int32)])
+        tot = cum.astype(jnp.int64).sum(1)
+        return jax.lax.psum(jnp.diff(tot), "shard")
 
     _run = jax.jit(jax.shard_map(
-        hist_fn, mesh=mesh,
-        in_specs=(P("shard"), P(), P()),
+        hist_fold, mesh=mesh,
+        in_specs=(P("shard"), P()),
         out_specs=P()))
 
-    def run(delivered, cols, base):
+    def run(delivered, base):
         with jax.enable_x64(True):
-            return _run(delivered, cols, base)
+            return _run(delivered, base)
 
+    run.jitted = _run
     return run
 
 

@@ -146,15 +146,18 @@ def test_shard_hist_runner_matches_host_fold():
     rng = np.random.default_rng(7)
     n, w = 96, 12
     delivered = rng.integers(-1, 1 << 12, size=(n, w)).astype(np.int32)
-    cols = np.array([0, 3, 3, 7, 11, 2, 0, 5], np.int32)
-    # a padded slot (base -1), a sentinel-high base, and normal bases
-    base = np.array([0, 5, -1, 40, 1, 9000, -1, 2], np.int32)
+    # one reference round per column: columns left out (base -1), a
+    # sentinel-high base, a base past every delivery, and normal bases
+    base = np.array([0, -1, 1, 5, -1, 2, -1, 40, -1, -1, 9000, 1],
+                    np.int32)
     mesh = shard_mesh(1)
     dev = jax.device_put(delivered, NamedSharding(mesh, PartitionSpec("shard")))
-    got = np.asarray(shard_hist_runner(1)(dev, cols, base))
-    da = delivered[:, cols].astype(np.int64)
+    got = np.asarray(shard_hist_runner(1)(dev, base))
+    assert got.dtype == np.int64 and got.shape == (NB,)
+    da = delivered.astype(np.int64)
     valid = (da >= 0) & (base >= 0)[None, :]
     want = hist_np((da - base[None, :].astype(np.int64))[valid])
+    assert want.sum() > 0
     np.testing.assert_array_equal(got, want)
 
 

@@ -13,11 +13,13 @@ from dataclasses import replace
 
 import jax
 import numpy as np
+import pytest
 
 from repro.core.vecsim import churn_scenario, static_scenario
 from repro.core.vecsim.live import LiveLoop
 from repro.core.vecsim.shard.driver import ShardedStepper
 from repro.core.vecsim.shard.spanner import PHASE_SCOPES, SEGMENT_PROGRAMS
+from repro.obs.hist import NB
 from repro.obs.spans import _MAX_DEPTH, NULL_RECORDER, EngineObs, \
     SpanRecorder
 
@@ -153,6 +155,61 @@ def test_retire_spans_change_no_result():
     assert s0 == s1
 
 
+def _fold_run(monkeypatch, on_device: bool, run: str):
+    """One churn run with the latency histogram folded on the chosen
+    path; the mesh-platform predicate is what selects it."""
+    from repro.core.vecsim.shard import driver
+    monkeypatch.setattr(driver, "_folds_on_device", lambda mesh: on_device)
+    if run == "live":
+        loop, obs, _, _ = _live(spans=True)
+        st = loop.stepper
+        res = None
+    else:
+        scn = churn_scenario(3, 64)
+        # recycling: four columns short, so retired columns are reused
+        w = scn.m_total if run == "stepper" else scn.m_total - 4
+        obs = EngineObs(histograms=True, spans=True)
+        st = ShardedStepper(scn, w, n_devices=1, seg_len=8, scan="on",
+                            obs=obs)
+        while not st.done:
+            st.advance()
+        res = st.finish()
+    assert st.fold_on_device is on_device
+    assert obs.spans.depth == 0 and obs.spans.dropped == 0
+    return st, obs, res
+
+
+@pytest.mark.parametrize("run", ["stepper", "stepper-recycling", "live"])
+def test_device_fold_matches_host_fold(monkeypatch, run):
+    """The histogram folded on the mesh (an accelerator mesh's path)
+    equals the host fold (a CPU mesh's path) byte for byte, changes no
+    result, and pulls only the (NB,) int64 totals per folding retire."""
+    host_st, host_obs, host_res = _fold_run(monkeypatch, False, run)
+    dev_st, dev_obs, dev_res = _fold_run(monkeypatch, True, run)
+    assert host_obs.latency_hist.sum() > 0
+    np.testing.assert_array_equal(host_obs.latency_hist,
+                                  dev_obs.latency_hist)
+    for key in ("series", "deliv_count", "deliv_round_sum", "bcast_done",
+                "expired"):
+        np.testing.assert_array_equal(getattr(host_st, key),
+                                      getattr(dev_st, key), err_msg=key)
+    assert (host_st.lat_sum, host_st.lat_cnt) == (dev_st.lat_sum,
+                                                  dev_st.lat_cnt)
+    if host_res is not None:
+        assert host_res.stats == dev_res.stats
+        np.testing.assert_array_equal(host_res.delivered, dev_res.delivered)
+        assert host_res.peak_live == dev_res.peak_live
+    evs = dev_obs.spans.events()
+    folds = [e for e in evs if e["name"] == "segment.retire.fold"]
+    pulled = [e["value"] for e in evs if e["name"] == "retire.hist_bytes"]
+    assert folds and len(pulled) == len(folds)
+    assert pulled == [NB * 8] * len(folds)
+    host_pulled = [e["value"] for e in host_obs.spans.events()
+                   if e["name"] == "retire.hist_bytes"]
+    assert len(host_pulled) == len(folds)
+    assert min(host_pulled) >= host_st.n_pad * 8
+
+
 def _op_name_components(text):
     out = set()
     for op_name in re.findall(r'op_name="([^"]*)"', text):
@@ -201,7 +258,8 @@ def test_segment_programs_carry_names_and_phase_scopes():
 
 
 def test_retire_and_gather_programs_are_named():
-    from repro.core.vecsim.shard.spanner import shard_column_gather
+    from repro.core.vecsim.shard.spanner import shard_column_gather, \
+        shard_hist_runner
     st = _run_stepper(churn_scenario(3, 64))
     w = st.w
     cols = np.zeros(8, np.int32)
@@ -209,6 +267,8 @@ def test_retire_and_gather_programs_are_named():
     with jax.enable_x64(True):
         lowered = {
             "jit_hist_gather": st._take.lower(st.state[1], cols, cols),
+            "jit_hist_fold": shard_hist_runner(1).jitted.lower(
+                st.state[1], np.full(w, -1, np.int32)),
             "jit_column_gather": shard_column_gather().lower(st.state[1],
                                                              cols),
             "jit_retire_reduce": st.reduce_run.jitted.lower(

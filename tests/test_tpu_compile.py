@@ -113,3 +113,23 @@ def test_sharded_scan_generic_body_compiles_for_v5e(topo, monkeypatch):
         compiled = run.jitted.lower(state, *rest).compile()
     _assert_fits(compiled)
     assert "input_output_alias" in compiled.as_text()
+
+
+def test_sharded_hist_fold_compiles_for_v5e(topo, monkeypatch):
+    """The on-device latency-histogram fold at the benchmark's N=2^18,
+    W=512 on one described device: it reads the delivered plane in
+    place, so its temporaries stay far below one (N, W) plane and the
+    retire step adds nothing to the segment's peak."""
+    from repro.core.vecsim.shard import spanner
+    n, w = 1 << 18, 512
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:1]), ("shard",))
+    monkeypatch.setattr(spanner, "shard_mesh", lambda d: mesh)
+    run = spanner.shard_hist_runner.__wrapped__(1)
+    plane = jax.ShapeDtypeStruct((n, w), np.int32,
+                                 sharding=NamedSharding(mesh, P("shard")))
+    base = jax.ShapeDtypeStruct((w,), np.int32,
+                                sharding=NamedSharding(mesh, P()))
+    with jax.enable_x64(True):
+        compiled = run.jitted.lower(plane, base).compile()
+    _assert_fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < n * w * 4 // 64

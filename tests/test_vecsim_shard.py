@@ -181,3 +181,37 @@ def test_sharded_four_devices_matrix_and_auto_selection_subprocess():
          ("waves", 4, 50, 1.0, 8)],       # 50 % 4 != 0: padding rows
         shards=4, extra=_AUTO_SELECT_SNIPPET)
     assert "AUTO_OK" in out
+
+
+_DEVICE_FOLD_SNIPPET = """
+from repro.core.vecsim import churn_scenario
+from repro.core.vecsim.shard import driver
+from repro.obs.spans import EngineObs
+
+def folded(on_device):
+    driver._folds_on_device = lambda mesh: on_device
+    scn = churn_scenario(4, 63)             # 63 rows on 2 shards: padding
+    obs = EngineObs(histograms=True)
+    st = driver.ShardedStepper(scn, scn.m_total - 4, n_devices=2,
+                               seg_len=8, scan="on", obs=obs)
+    assert st.fold_on_device is on_device
+    while not st.done:
+        st.advance()
+    return st.finish(), obs.latency_hist
+
+host, host_hist = folded(False)
+dev, dev_hist = folded(True)
+assert host_hist.sum() > 0
+np.testing.assert_array_equal(host_hist, dev_hist)
+np.testing.assert_array_equal(host.series, dev.series)
+np.testing.assert_array_equal(host.delivered, dev.delivered)
+print("FOLD_OK")
+"""
+
+
+def test_device_fold_psums_across_two_shards_subprocess():
+    """The histogram folded on a 2-device mesh (psum of each shard's
+    counts, padding rows included) equals the host fold."""
+    out = run_shard_matrix_subprocess([], shards=2,
+                                      extra=_DEVICE_FOLD_SNIPPET)
+    assert "FOLD_OK" in out
