@@ -288,8 +288,9 @@ class ShardedStepper:
                      for name in ("stage", "dispatch", "block", "retire",
                                   "retire.pull", "retire.gather",
                                   "retire.fold", "retire.apply")}
-        self._cid = {name: self._rec.name(f"retire.{name}")
-                     for name in ("columns", "hist_bytes")}
+        self._cid = {name: self._rec.name(name)
+                     for name in ("retire.columns", "retire.hist_bytes",
+                                  "pong.queries", "pong.chunks")}
         # segment programs dispatched so far, each with the abstract
         # arguments of its first dispatch (program_texts)
         self._programs: Dict[object, tuple] = {}
@@ -587,7 +588,7 @@ class ShardedStepper:
                                     retire & cw.slot_app, hung)
         cw.free_cols(cols)
         rec.end()
-        rec.counter(self._cid["columns"], len(cols))
+        rec.counter(self._cid["retire.columns"], len(cols))
 
     def _device_fold(self, acols: np.ndarray, bb: np.ndarray) -> None:
         """Fold the latency histogram of the retiring columns on the
@@ -604,7 +605,7 @@ class ShardedStepper:
         counts = np.asarray(tot)
         self.obs.add_hist(counts)
         rec.end()
-        rec.counter(self._cid["hist_bytes"], counts.nbytes)
+        rec.counter(self._cid["retire.hist_bytes"], counts.nbytes)
 
     def _host_fold(self, acols: np.ndarray, bb: np.ndarray) -> None:
         """Fold the latency histogram of the retiring columns on the
@@ -630,7 +631,7 @@ class ShardedStepper:
         counts = np.bincount(idx.ravel(), minlength=NB + 1)
         self.obs.add_hist(counts[:NB].astype(np.int64))
         rec.end()
-        rec.counter(self._cid["hist_bytes"], idx.nbytes)
+        rec.counter(self._cid["retire.hist_bytes"], idx.nbytes)
 
     def _retire(self, t_now: int, red_dev=None) -> int:
         """Retire columns from the fused segment aggregates (scanned
@@ -644,10 +645,10 @@ class ShardedStepper:
             red_dev = self.reduce_run(
                 self.state, self._column_origins(), self.rounds_dev)
         self._rec.begin(self._sid["retire.pull"])
-        red = tuple(np.asarray(x) for x in red_dev)
+        red = tuple(np.asarray(x) for x in red_dev[:8])
         self._rec.end()
         (cnt, arrcnt, sumdel, alive, alivedel, blockcnt, refcnt,
-         bdone) = red[:8]
+         bdone) = red
         full_del = alivedel == int(alive)
         blocked = (blockcnt > 0) & cw.slot_app
         ref = refcnt > 0
@@ -699,13 +700,20 @@ class ShardedStepper:
             self.stager.prefetch(t_end)
         t0 = self._clock()
         self._rec.begin(self._sid["block"])
-        self.series[t:t_end] = np.asarray(stats_dev, np.int64)[: t_end - t]
+        # the gated body's pong pair (slots queried, chunks run) comes
+        # home in the same pull as the series
+        pong = tuple(red_dev[8:]) if red_dev is not None else ()
+        stats, *pong = self._jax.device_get((stats_dev,) + pong)
+        self.series[t:t_end] = np.asarray(stats, np.int64)[: t_end - t]
         if (self.snapshot_round is not None
                 and t_end - 1 == self.snapshot_round):
             self.snapshot = self.host_state()
             self.snapshot["is_app"] = self.cw.slot_app.copy()
             self.snapshot["slot_msg"] = self.cw.slot_msg.copy()
         self._rec.end()
+        for queries, chunks in pong:
+            self._rec.counter(self._cid["pong.queries"], int(queries))
+            self._rec.counter(self._cid["pong.chunks"], int(chunks))
         t1 = self._clock()
         self._rec.begin(self._sid["retire"])
         self._retire(t_end, red_dev)

@@ -14,10 +14,15 @@ index stream are replicated.  Per round, three things cross shards:
     Scatter-min is associative/commutative on ints, so the result is
     bit-equal to the monolithic global scatter regardless of hop order;
   * **pong query ring** — pong detection reads ``delivered[q, s]`` at the
-    gated link's remote target; the ``(N/D, K)`` query triples (target,
-    ping slot, answer) ride a second ring and come home after D hops.
-    This ring is K columns wide, not W, and is elided entirely (with the
-    whole gating machinery) when the scenario schedules no additions;
+    gated link's remote target, for the candidate slots only (a live
+    gate, flush pending, ping set, process up).  Their rows are compacted
+    by rank and asked in chunks of :data:`PONG_ROWS` rows: each chunk's
+    ``(PONG_ROWS, K)`` query triples (target, ping slot, answer) ride a
+    second ring and come home after D hops.  Every shard runs the same
+    number of chunks (a ``pmax`` of the per-shard count), so the ring's
+    hops match; a round with no candidate runs none.  Elided
+    entirely (with the whole gating machinery) when the scenario
+    schedules no additions;
   * **stats psum** — the per-round series row is ``psum``-reduced so every
     shard returns the identical replicated ``(rounds, 6)`` series.
 
@@ -63,7 +68,8 @@ __all__ = ["shard_span_runner", "shard_fast_span_runner",
            "shard_retire_kernels", "shard_hist_runner",
            "shard_column_gather",
            "resolve_shard_backend", "resolve_scan", "STATE_KEYS",
-           "INT16_LIMIT", "SEGMENT_PROGRAMS", "PHASE_SCOPES"]
+           "INT16_LIMIT", "PONG_ROWS", "SEGMENT_PROGRAMS",
+           "PHASE_SCOPES"]
 
 STATE_KEYS = _STATE_KEYS
 
@@ -76,6 +82,12 @@ SEGMENT_PROGRAMS = ("segment_gated", "segment_fast")
 PHASE_SCOPES = ("fold", "removals", "additions", "crashes", "broadcasts",
                 "deliveries", "pong", "flush_forward", "stats",
                 "retire_reduce", "convert")
+
+# Rows of the (N/D, K) tables one chunk of the pong query reads (clamped
+# to N/D).  A round answers its candidate rows in ceil(rows / PONG_ROWS)
+# chunks, so a chunk is a fixed-shape gather of PONG_ROWS * K slots of
+# ``delivered`` and the read volume follows the live gates, not N.
+PONG_ROWS = 128
 
 # int16 ceiling of the fast scanned body: arrival rounds live in int16
 # planes there, with this value standing in for INF.  The driver only
@@ -171,7 +183,9 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
     row-block-sharded global arrays.  Scanned (``scan=True``) it takes
     ``(state, sched, ts, origins, rounds)`` and additionally returns the
     fused per-column retirement aggregates (``_column_partials``,
-    psum'd), so a segment is one dispatch with no standalone reduce.
+    psum'd), so a segment is one dispatch with no standalone reduce;
+    with gating live the aggregates carry a ninth entry, the segment's
+    int64 pong pair (candidate slots queried, chunks run).
     Negative rounds in ``ts`` are padding and leave the state untouched.
     One compilation per (mesh, shape) signature, cached.
 
@@ -307,30 +321,63 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 newly = (arr == t) & (delivered < 0) & ~crashed[:, None]
                 delivered = jnp.where(newly, t, delivered)
 
-        # -- 6. pong detection: the query ring ---------------------------- #
+        # -- 6. pong detection: the compacted query ring ------------------ #
+        # (slots queried, chunks run) this round, for the stepper's counters
+        pong = jnp.zeros(2, jnp.int64)
         if pc and gating:
             with jax.named_scope("pong"):
-                # Exactly the monolithic read delivered[clip(adj),
-                # clip(ping)] for *every* slot, masked afterwards — the
-                # triples visit all D shards and come home with the
-                # answer filled in by the target row's owner.
-                q = jnp.clip(adj, 0, n_loc * d - 1).reshape(-1)
-                s = jnp.clip(ping, 0, width - 1).reshape(-1)
-                ans = jnp.full(q.shape, jnp.int32(-1))
-                for _hop in range(d):
-                    ql = q - off
-                    hit = (ql >= 0) & (ql < n_loc)
-                    qcl = jnp.clip(ql, 0, n_loc - 1)
-                    ans = jnp.where(hit, delivered[qcl, s], ans)
-                    if d > 1:
-                        q = jax.lax.ppermute(q, "shard", perm)
-                        s = jax.lax.ppermute(s, "shard", perm)
-                        ans = jax.lax.ppermute(ans, "shard", perm)
-                tgt_del = ans.reshape(adj.shape)
-                fire = ((gate >= 0) & (flush == inf) & (ping >= 0)
-                        & (tgt_del >= 0) & ~crashed[:, None])
-                flush = jnp.where(fire, t + pong_delay, flush)
-                stats = stats.at[4].set(fire.sum().astype(jnp.int64))
+                # The monolithic fire mask, less its one remote conjunct
+                # delivered[adj, ping] >= 0: only these slots need asking.
+                cand = ((gate >= 0) & (flush == inf) & (ping >= 0)
+                        & ~crashed[:, None])
+                m = min(PONG_ROWS, n_loc)
+                # rank[r] = candidate rows in [0, r]; chunk c asks the
+                # rows of rank c*m+1 .. c*m+m (n_loc where there are
+                # fewer: dropped)
+                rank = jnp.cumsum(cand.any(axis=1), dtype=jnp.int32)
+                chunks = (rank[-1] + (m - 1)) // m
+                if d > 1:
+                    # the ring's ppermutes must match on every shard
+                    chunks = jax.lax.pmax(chunks, "shard")
+                first = jnp.arange(1, m + 1, dtype=jnp.int32)
+                slots = jnp.arange(k, dtype=jnp.int32)[None, :]
+
+                def ask(c, carry):
+                    flush, fired = carry
+                    rows = jnp.searchsorted(rank, c * m + first,
+                                            side="left").astype(jnp.int32)
+                    # point indices (row, slot): the chip lays the tables
+                    # out row-minor, and a row-slice gather would re-lay
+                    # each whole table out every round
+                    at = (jnp.minimum(rows, n_loc - 1)[:, None], slots)
+                    want = cand[at] & (rows < n_loc)[:, None]
+                    # the chunk's triples visit all D shards and come
+                    # home with the answer filled in by the target's owner
+                    q = jnp.clip(adj[at], 0, n_loc * d - 1).reshape(-1)
+                    s = jnp.clip(ping[at], 0, width - 1).reshape(-1)
+                    ans = jnp.full(q.shape, jnp.int32(-1))
+                    for _hop in range(d):
+                        ql = q - off
+                        hit = (ql >= 0) & (ql < n_loc)
+                        qcl = jnp.clip(ql, 0, n_loc - 1)
+                        ans = jnp.where(hit, delivered[qcl, s], ans)
+                        if d > 1:
+                            q = jax.lax.ppermute(q, "shard", perm)
+                            s = jax.lax.ppermute(s, "shard", perm)
+                            ans = jax.lax.ppermute(ans, "shard", perm)
+                    fire = want & (ans.reshape(want.shape) >= 0)
+                    # a candidate's flush is INF, so min sets it
+                    flush = flush.at[rows[:, None], slots].min(
+                        jnp.where(fire, t + pong_delay, inf), mode="drop")
+                    return flush, fired + fire.sum(dtype=jnp.int64)
+
+                # chunks never share a row and no answer reads flush, so
+                # this equals the read-everything-then-mask body exactly
+                flush, fired = jax.lax.fori_loop(
+                    0, chunks, ask, (flush, jnp.int64(0)))
+                stats = stats.at[4].set(fired)
+                pong = jnp.stack([cand.sum(dtype=jnp.int64),
+                                  chunks.astype(jnp.int64)])
 
         # -- 7+8. flush + forward: the frontier exchange ------------------ #
         # Per link slot, the flush contributions (phase 7) and this
@@ -413,25 +460,27 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
         out = (arr, delivered, adj, delay, active, gate, flush, ping,
                crashed, ever_del)
         if deferred:
-            return (out, dest), stats
-        return out, stats
+            return (out, dest), stats, pong
+        return out, stats, pong
 
     def step(sched, state, t):
         t = t.astype(jnp.int32)
         return jax.lax.cond(
             t >= 0,
-            lambda s: real_step(sched, s, t),
+            lambda s: real_step(sched, s, t)[:2],
             lambda s: (s, jnp.zeros(len(SERIES_FIELDS), jnp.int64)),
             state)
 
     if scan:
         def scan_step(sched, carry, t):
             t = t.astype(jnp.int32)
-            return jax.lax.cond(
+            carry, stats, pong = jax.lax.cond(
                 t >= 0,
                 lambda c: real_step(sched, c[0], t, c[1]),
-                lambda c: (c, jnp.zeros(len(SERIES_FIELDS), jnp.int64)),
+                lambda c: (c, jnp.zeros(len(SERIES_FIELDS), jnp.int64),
+                           jnp.zeros(2, jnp.int64)),
                 carry)
+            return carry, (stats, pong)
 
         def segment_gated(state, sched, ts, origins, rounds):
             is_app = sched["is_app"]
@@ -444,7 +493,7 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 sch["is_app"] = is_app
                 return scan_step(sch, carry, t)
 
-            (state, pending), stats = jax.lax.scan(
+            (state, pending), (stats, pong) = jax.lax.scan(
                 body, (tuple(state), pending0), (ts, events))
             # residual fold: the last round's in-flight frontier (padding
             # rounds skip real_step, so pending survives to here intact)
@@ -461,6 +510,12 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 red = tuple(jax.lax.psum(x, "shard")
                             for x in _column_partials(state, origins,
                                                       rounds, off))
+            if pc and gating:
+                # the segment's pong (slots queried, chunks run): the
+                # chunk count is already uniform across shards
+                pong = pong.sum(axis=0)
+                red += (jnp.stack([jax.lax.psum(pong[0], "shard"),
+                                   pong[1]]),)
             return state, stats, red
         span = segment_gated
     else:

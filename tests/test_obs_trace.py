@@ -19,6 +19,7 @@ from repro.core.vecsim import churn_scenario, static_scenario
 from repro.core.vecsim.live import LiveLoop
 from repro.core.vecsim.shard.driver import ShardedStepper
 from repro.core.vecsim.shard.spanner import PHASE_SCOPES, SEGMENT_PROGRAMS
+from repro.core.vecsim.sim import SERIES_FIELDS
 from repro.obs.hist import NB
 from repro.obs.spans import _MAX_DEPTH, NULL_RECORDER, EngineObs, \
     SpanRecorder
@@ -287,3 +288,38 @@ def test_stepped_path_reports_its_program():
     st.advance()
     assert [_module(t) for t in st.program_texts()] == [
         "jit_segment_stepped"]
+
+
+def _pong_counters(scn):
+    obs = EngineObs(histograms=False, spans=True)
+    st = ShardedStepper(scn, scn.m_total, n_devices=1, seg_len=8,
+                        scan="on", obs=obs)
+    while not st.done:
+        st.advance()
+    st.finish()
+    evs = obs.spans.events()
+    got = {name: [e for e in evs if e["name"] == name]
+           for name in ("segment.dispatch", "pong.queries", "pong.chunks")}
+    return st, got
+
+
+def test_pong_counters_per_gated_dispatch():
+    """Every dispatch of the gated body records how many candidate slots
+    its pong query asked and how many chunks it ran; the slots asked
+    bound the pongs that fired.  With no link additions there is no
+    pong phase and no counter."""
+    st, got = _pong_counters(churn_scenario(3, 64))
+    n = len(got["segment.dispatch"])
+    assert n > 1
+    for name in ("pong.queries", "pong.chunks"):
+        assert len(got[name]) == n, name
+        assert all(e["kind"] == "counter" for e in got[name])
+    queries = sum(e["value"] for e in got["pong.queries"])
+    chunks = sum(e["value"] for e in got["pong.chunks"])
+    pongs = int(st.series[:, SERIES_FIELDS.index("pongs")].sum())
+    assert queries >= pongs > 0
+    assert chunks > 0
+
+    _, got = _pong_counters(static_scenario(3, 64))
+    assert got["segment.dispatch"]
+    assert not got["pong.queries"] and not got["pong.chunks"]

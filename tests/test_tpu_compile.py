@@ -10,6 +10,7 @@ worker given this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import numpy as np
@@ -87,10 +88,18 @@ def test_windowed_span_runner_compiles_for_v5e(topo):
     _assert_fits(compiled)
 
 
+def _gather_sizes(hlo: str):
+    """Element count of every ``gather`` result in compiled HLO text."""
+    return [int(np.prod([int(x) for x in dims.split(",") if x]))
+            for dims in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", hlo)]
+
+
 def test_sharded_scan_generic_body_compiles_for_v5e(topo, monkeypatch):
     """The sharded engine's scanned generic body (gating live: a churn
     scenario) on one described device; the runner's mesh is steered onto
-    the described device here, in the test."""
+    the described device here, in the test.  Its pong query reads only
+    the candidate rows' slots, in chunks: no gather spans every (row,
+    slot) of the (N, K) tables."""
     from repro.core.vecsim.shard import spanner
     n, w, seg_len = 1 << 14, 128, 8
     scn = _scenario(n, traffic="uniform", dynamics="churn")
@@ -112,7 +121,11 @@ def test_sharded_scan_generic_body_compiles_for_v5e(topo, monkeypatch):
     with jax.enable_x64(True):
         compiled = run.jitted.lower(state, *rest).compile()
     _assert_fits(compiled)
-    assert "input_output_alias" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "input_output_alias" in hlo
+    sizes = _gather_sizes(hlo)
+    assert sizes and n * scn.k not in sizes, sizes
+    assert spanner.PONG_ROWS * scn.k in sizes, sizes
 
 
 def test_sharded_hist_fold_compiles_for_v5e(topo, monkeypatch):

@@ -215,3 +215,49 @@ def test_device_fold_psums_across_two_shards_subprocess():
     out = run_shard_matrix_subprocess([], shards=2,
                                       extra=_DEVICE_FOLD_SNIPPET)
     assert "FOLD_OK" in out
+
+
+_PONG_CHUNKS_SNIPPET = """
+from repro.core.vecsim.shard import spanner
+from repro.obs.spans import EngineObs
+
+scn = build("churn", 7, 64)
+w = scn.m_total
+win = execute_windowed(scn, w, backend="numpy", collect="full", seg_len=8)
+for rows in (1, 2):
+    # rebuild the runners so they trace with the chunk size patched in
+    spanner.PONG_ROWS = rows
+    spanner.shard_span_runner.cache_clear()
+    for scan, seg in (("on", 1), ("on", 8), ("off", 8)):
+        obs = EngineObs(histograms=False, spans=True)
+        sh = execute_sharded(scn, w, n_devices={shards}, collect="full",
+                             seg_len=seg, scan=scan, obs=obs)
+        tag = (rows, scan, seg)
+        np.testing.assert_array_equal(win.delivered, sh.delivered)
+        np.testing.assert_array_equal(win.series, sh.series)
+        assert win.stats == sh.stats, tag
+        for key in win.state:
+            np.testing.assert_array_equal(win.state[key], sh.state[key],
+                                          err_msg=str(tag) + key)
+        if seg == 1:
+            # one round per segment: the counters are per round
+            chunks = [e["value"] for e in obs.spans.events()
+                      if e["name"] == "pong.chunks"]
+            assert len(chunks) == scn.rounds, tag
+            assert min(chunks) == 0, tag
+            if rows == 1:
+                assert max(chunks) >= 2, (tag, max(chunks))
+print("PONG_OK")
+"""
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_pong_chunks_match_windowed_subprocess(shards):
+    """The compacted pong query with one or two rows per chunk, so that
+    one round runs several chunks (and rounds with no candidate run
+    none): the scanned and the stepped sharded bodies stay byte-identical
+    to the windowed engine on a churn scenario."""
+    out = run_shard_matrix_subprocess(
+        [], shards=shards,
+        extra=_PONG_CHUNKS_SNIPPET.replace("{shards}", str(shards)))
+    assert "PONG_OK" in out
