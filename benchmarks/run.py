@@ -1,5 +1,5 @@
-"""Benchmark harness — one module per paper table/figure + substrate
-benches.  Prints ``name,us_per_call,derived`` CSV.
+"""Benchmark harness — one module per paper table/figure plus the
+engine bench.  Prints ``name,us_per_call,derived`` CSV.
 
 ``--engine exact`` (default) runs the paper-scale reproductions on the
 discrete-event simulator; ``--engine vec`` runs the Table 1 / Fig. 7
@@ -9,9 +9,10 @@ windowed engine; ``--engine both`` runs the two back to back.
 ``--window`` routes every vec-engine sweep through the streaming
 windowed engine with that many live columns.  ``--scale-devices D``
 additionally runs a harness-sized point of the device-sharded scale
-bench (``bench_scale``) on a D-device mesh.  The substrate benches
-(engine/train) are engine-independent and always run.  All protocol
-benches dispatch through ``repro.api.run`` (one spec, one front door).
+bench (``bench_scale``) on a D-device mesh.  The jitted-engine bench
+(``bench_engine``) is independent of ``--engine`` and always runs.  All
+protocol benches dispatch through ``repro.api.run`` (one spec, one front
+door).
 """
 
 from __future__ import annotations
@@ -65,8 +66,7 @@ def main() -> None:
                 f"{args.scale_devices}").strip()
     # imported after the device-count env var so it precedes jax init
     from benchmarks import bench_backend, bench_engine, bench_fig7, \
-        bench_scale, bench_serve, bench_table1, bench_throughput, \
-        bench_train
+        bench_scale, bench_serve, bench_table1, bench_throughput
     engines = ("exact", "vec") if args.engine == "both" else (args.engine,)
 
     print("name,us_per_call,derived")
@@ -141,13 +141,12 @@ def main() -> None:
             except Exception:                  # noqa: BLE001
                 failed += 1
                 traceback.print_exc()
-    for mod in (bench_engine, bench_train):
-        try:
-            for name, us, derived in mod.rows():
-                print(f"{name},{us:.2f},{derived:.3f}", flush=True)
-        except Exception:                      # noqa: BLE001
-            failed += 1
-            traceback.print_exc()
+    try:
+        for name, us, derived in bench_engine.rows():
+            print(f"{name},{us:.2f},{derived:.3f}", flush=True)
+    except Exception:                          # noqa: BLE001
+        failed += 1
+        traceback.print_exc()
     if failed:
         sys.exit(1)
 

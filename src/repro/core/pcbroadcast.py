@@ -59,7 +59,7 @@ class PCBroadcast(RBroadcast):
         # route is sent over the gated link itself.  That is safe exactly
         # when no pre-gate message can still be in flight toward the
         # target — true for fresh joiners whose history arrives by state
-        # transfer — so the runtime enables it only on join links.
+        # transfer — so enable it only for joiners bootstrapped that way.
         self.direct_ping_fallback = direct_ping_fallback
         self.n_delivered = 0
         self.ctrl_counter = 0

@@ -1,5 +1,5 @@
 """Pallas delivery-sweep kernels for the vecsim hot path (DESIGN.md
-§2.6) — kernel/ops/ref layout mirroring ``repro.kernels``:
+§2.6), one module per role:
 
   * ``kernel.py`` — the Pallas kernels (column-tiled grid, row-loop
     scatter-min);

@@ -109,3 +109,52 @@ def test_churn_storm_stays_causal(ping_mode):
     assert rep.causal_ok and not rep.double_deliveries, rep.summary()
     # The ring survived, so agreement holds too:
     assert rep.ok, rep.summary()
+
+
+@pytest.mark.parametrize("fallback", [True, False])
+def test_fresh_joiner_bootstrap(fallback):
+    """DESIGN.md §2.2: a process whose only links are new has no safe
+    path for the pings of its inbound links.  ``direct_ping_fallback``
+    sends such a ping over the gated link itself, so the phase completes
+    and the joiner delivers every later broadcast; without it the
+    inbound links stay gated.  A graceful departure mid-run leaves the
+    survivors causal either way."""
+    n, joiner, leaver = 8, 8, 5
+    net = Network(seed=3, default_delay=1.0, oob_delay=0.5)
+    for pid in range(n):
+        net.add_process(PCBroadcast(pid, ping_mode="route",
+                                    direct_ping_fallback=fallback))
+    ring_plus_random(net, range(n), k=3)
+    for pid in (0, 4):
+        net.procs[pid].broadcast(("history", pid))
+    net.run()
+    # history reaches the joiner by state transfer, not over the overlay
+    net.add_process(PCBroadcast(joiner, ping_mode="route",
+                                direct_ping_fallback=fallback))
+    neighbors = (1, 3, 6)
+    for q in neighbors:
+        net.connect(joiner, q)
+        net.connect(q, joiner)
+    # the joiner's own links are safe (it has delivered nothing); every
+    # inbound one is gated behind a ping with no safe route
+    assert set(net.procs[joiner].Q) == set(neighbors)
+    assert all(joiner in net.procs[q].B for q in neighbors)
+    net.run()
+    net.depart(leaver)
+    net.procs[2].broadcast("late")
+    net.procs[joiner].broadcast("hello")
+    net.run()
+    rep = check_trace(net.trace, crashed={leaver}, check_agreement=False)
+    assert rep.causal_ok and not rep.double_deliveries, rep.summary()
+    got = {pid: {m.payload for m in net.procs[pid].delivered_log}
+           for pid in net.procs if pid != leaver}
+    assert all("hello" in g and "late" in g
+               for pid, g in got.items() if pid != joiner)
+    if fallback:
+        assert all(joiner in net.procs[q].Q and joiner not in net.procs[q].B
+                   for q in neighbors)
+        assert got[joiner] == {"late", "hello"}
+    else:
+        assert all(joiner in net.procs[q].B and joiner not in net.procs[q].Q
+                   for q in neighbors)
+        assert got[joiner] == {"hello"}

@@ -1,10 +1,6 @@
-"""End-to-end behaviour tests: the full stack from paper protocol to the
-causal-gossip training runtime (the framework's flagship path).
-
-The protocol-level end-to-end (Fig. 7 style) runs here; training-runtime
-end-to-end tests live in tests/test_gossip.py once the runtime stack is
-imported on top.
-"""
+"""End-to-end behaviour test of the exact protocol stack (Fig. 7 style):
+a dynamic overlay with churn, lossy pongs and silent crashes, checked by
+the happens-before oracle and the overhead/reachability diagnostics."""
 
 import pytest
 

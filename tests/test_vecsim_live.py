@@ -9,13 +9,18 @@ The serving loop's correctness claims, each tested directly:
   * a live run is a *scheduler*, not a new engine: the finally-admitted
     schedule replayed pre-scripted through the same engine reproduces
     the live run's delivered matrix, series and stats byte-for-byte
-    (windowed and sharded, churn included);
+    (windowed, and the scanned sharded path on every dynamics of
+    ``vecsim_cases.BUILDERS`` under each arrival process and admission
+    policy);
+  * the served sharded path's ``collect="aggregate"`` (what the chip
+    benchmark runs) reports exactly what ``collect="full"`` does;
   * the capacity-blind ``admit`` policy drives the engine into overflow
     and the loop serves every message anyway (catch, withdraw, requeue,
     retry — zero loss);
   * rounds-to-delivery latency (queueing delay included) cross-validates
     against the exact event simulator on the admitted schedule, at N ∈
-    {64, 256} and under churn: the mean over per-message mean delivery
+    {64, 256} and under churn, through the windowed engine and the
+    scanned sharded one: the mean over per-message mean delivery
     rounds, and the p50/p99 as per-delivery histogram read-outs
     (repro.obs, DESIGN.md §2.10);
   * the ingest accounting identity holds under shedding;
@@ -24,6 +29,9 @@ The serving loop's correctness claims, each tested directly:
 """
 
 import io
+from dataclasses import replace
+from functools import lru_cache
+
 import numpy as np
 import pytest
 
@@ -36,10 +44,35 @@ from repro.core.vecsim.scenario import churn_scenario, static_scenario
 from repro.core.vecsim.stream import (ColumnWindow, WindowOverflowError,
                                       execute_windowed)
 from repro.obs.hist import hist_np, percentiles_from_hist
+from vecsim_cases import BUILDERS
 
 
 def _base(seed, n, **kw):
     return static_scenario(seed, n, k=4, m_app=0, **kw)
+
+
+def _served(scn):
+    """``scn`` with its scripted broadcasts stripped: a live base whose
+    traffic comes only from the arrival process."""
+    return replace(scn, bcast_round=np.empty(0, np.int32),
+                   bcast_origin=np.empty(0, np.int32)).validate()
+
+
+def _live_engine(engine):
+    """LiveLoop engine arguments: the windowed numpy engine, or the
+    scanned sharded one the chip benchmark serves through."""
+    if engine == "windowed":
+        return dict(engine="windowed", backend="numpy")
+    return dict(engine="sharded", devices=1, scan="on")
+
+
+def _replay(engine, scn, window):
+    """Replay an admitted schedule pre-scripted through ``engine``."""
+    if engine == "windowed":
+        return execute_windowed(scn, window, backend="numpy", seg_len=32)
+    from repro.core.vecsim.shard import execute_sharded
+    return execute_sharded(scn, window, n_devices=1, scan="on",
+                           seg_len=32)
 
 
 # --------------------------------------------------------------------- #
@@ -170,17 +203,66 @@ def test_live_byte_identity_windowed(arrivals, admission):
     _assert_replay_identical(rep, res2)
 
 
-def test_live_byte_identity_sharded_scan():
-    from repro.core.vecsim.shard import execute_sharded
-    scn = _base(5, 64)
-    loop = LiveLoop(scn, 16, engine="sharded", devices=1, scan="on",
-                    arrivals="poisson", admission="defer",
-                    rate=4.0, messages=120, queue_cap=512, seed=7)
-    rep = loop.run()
-    assert rep.admitted == 120 and rep.delivered_messages == 120
-    res2 = execute_sharded(rep.scenario, 16, n_devices=1, scan="on",
-                           seg_len=32)
-    _assert_replay_identical(rep, res2)
+# The served sharded grid: every dynamics of the differential suites
+# (their scripted broadcasts stripped) under every arrival process and
+# both admitting policies.  The window is small enough that ``admit``
+# overflows and is caught on several dynamics, and large enough that no
+# base's own pings overflow it.
+SERVE_N, SERVE_W, SERVE_M, SERVE_SEG = 32, 24, 40, 8
+SERVE_GRID = [(name, arrivals, admission) for name in BUILDERS
+              for arrivals in ("poisson", "bursty", "diurnal")
+              for admission in ("defer", "admit")]
+
+
+@lru_cache(maxsize=None)
+def _live_sharded(name, arrivals, admission, collect):
+    """One served sharded run of the grid: its report and the latency
+    histogram it merged (cached; two tests read each full run)."""
+    loop = LiveLoop(_served(BUILDERS[name](5, SERVE_N)), SERVE_W,
+                    seg_len=SERVE_SEG, collect=collect,
+                    arrivals=arrivals, admission=admission, rate=4.0,
+                    messages=SERVE_M, queue_cap=512, seed=7,
+                    **_live_engine("sharded"))
+    return loop.run(), loop.obs.latency_hist.copy()
+
+
+def _undeliverable(scn):
+    """Admitted broadcasts whose origin crashed at or before their
+    round: they are never delivered."""
+    crashed = dict(zip(scn.crash_pid.tolist(), scn.crash_round.tolist()))
+    return sum(1 for r, o in zip(scn.bcast_round.tolist(),
+                                 scn.bcast_origin.tolist())
+               if o in crashed and crashed[o] <= r)
+
+
+@pytest.mark.parametrize("name,arrivals,admission", SERVE_GRID)
+def test_live_byte_identity_sharded_scan(name, arrivals, admission):
+    rep, _ = _live_sharded(name, arrivals, admission, "full")
+    assert rep.admitted == SERVE_M
+    assert (rep.delivered_messages
+            == rep.admitted - _undeliverable(rep.scenario))
+    _assert_replay_identical(rep, _replay("sharded", rep.scenario,
+                                          SERVE_W))
+
+
+@pytest.mark.parametrize("name,arrivals,admission", SERVE_GRID)
+def test_live_sharded_aggregate_matches_full(name, arrivals, admission):
+    full, hist_full = _live_sharded(name, arrivals, admission, "full")
+    agg, hist_agg = _live_sharded(name, arrivals, admission, "aggregate")
+    assert full.result.delivered is not None
+    assert agg.result.delivered is None
+    assert hist_full.sum() > 0
+    np.testing.assert_array_equal(hist_agg, hist_full)
+    assert (agg.p50, agg.p99) == (full.p50, full.p99)
+    np.testing.assert_array_equal(agg.result.series, full.result.series)
+    assert agg.result.stats == full.result.stats
+    assert ((agg.admitted, agg.delivered_messages)
+            == (full.admitted, full.delivered_messages))
+    np.testing.assert_array_equal(agg.submit_round, full.submit_round)
+    np.testing.assert_array_equal(agg.result.deliv_count,
+                                  full.result.deliv_count)
+    np.testing.assert_array_equal(agg.result.deliv_round_sum,
+                                  full.result.deliv_round_sum)
 
 
 def test_admit_policy_catches_overflow_and_loses_nothing():
@@ -260,13 +342,13 @@ def _exact_delivery_latencies(adm, submit, seed):
     return np.rint(np.asarray(lat)).astype(np.int64)
 
 
+@pytest.mark.parametrize("engine", ["windowed", "sharded"])
 @pytest.mark.parametrize("n,messages", [(64, 150), (256, 300)])
-def test_latency_crossval_vs_exact(n, messages):
+def test_latency_crossval_vs_exact(n, messages, engine):
     scn = _base(21, n)
-    loop = LiveLoop(scn, max(16, n // 4), engine="windowed",
-                    backend="numpy", arrivals="poisson",
+    loop = LiveLoop(scn, max(16, n // 4), arrivals="poisson",
                     admission="defer", rate=4.0, messages=messages,
-                    queue_cap=1 << 14, seed=5)
+                    queue_cap=1 << 14, seed=5, **_live_engine(engine))
     rep = loop.run()
     assert rep.delivered_messages == messages
     mean = _exact_mean_delivery_rounds(rep.scenario, seed=5)
@@ -281,14 +363,12 @@ def test_latency_crossval_vs_exact(n, messages):
     assert (rep.p50, rep.p99) == (p50, p99)
 
 
-def test_latency_crossval_churn_during_serving():
-    base = churn_scenario(17, 64, k=5, m_app=6, n_adds=5, n_rms=4)
-    from dataclasses import replace
-    scn = replace(base, bcast_round=np.empty(0, np.int32),
-                  bcast_origin=np.empty(0, np.int32)).validate()
-    loop = LiveLoop(scn, 24, engine="windowed", backend="numpy",
-                    arrivals="poisson", admission="defer", rate=3.0,
-                    messages=120, queue_cap=1 << 12, seed=29)
+@pytest.mark.parametrize("engine", ["windowed", "sharded"])
+def test_latency_crossval_churn_during_serving(engine):
+    scn = _served(churn_scenario(17, 64, k=5, m_app=6, n_adds=5, n_rms=4))
+    loop = LiveLoop(scn, 24, arrivals="poisson", admission="defer",
+                    rate=3.0, messages=120, queue_cap=1 << 12, seed=29,
+                    **_live_engine(engine))
     rep = loop.run()
     assert rep.delivered_messages == 120
     mean = _exact_mean_delivery_rounds(rep.scenario, seed=29)
@@ -298,8 +378,7 @@ def test_latency_crossval_churn_during_serving():
     p50, p99 = percentiles_from_hist(hist_np(lat_del), (50.0, 99.0))
     assert (rep.p50, rep.p99) == (p50, p99)
     # and the delivered multiset itself matches the exact engine
-    res2 = execute_windowed(rep.scenario, 24, backend="numpy", seg_len=32)
-    _assert_replay_identical(rep, res2)
+    _assert_replay_identical(rep, _replay(engine, rep.scenario, 24))
 
 
 # --------------------------------------------------------------------- #
