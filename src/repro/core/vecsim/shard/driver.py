@@ -290,7 +290,9 @@ class ShardedStepper:
                                   "retire.fold", "retire.apply")}
         self._cid = {name: self._rec.name(name)
                      for name in ("retire.columns", "retire.hist_bytes",
-                                  "pong.queries", "pong.chunks")}
+                                  "pong.queries", "pong.chunks",
+                                  "frontier.columns",
+                                  "frontier.full_rounds")}
         # segment programs dispatched so far, each with the abstract
         # arguments of its first dispatch (program_texts)
         self._programs: Dict[object, tuple] = {}
@@ -700,10 +702,10 @@ class ShardedStepper:
             self.stager.prefetch(t_end)
         t0 = self._clock()
         self._rec.begin(self._sid["block"])
-        # the gated body's pong pair (slots queried, chunks run) comes
-        # home in the same pull as the series
-        pong = tuple(red_dev[8:]) if red_dev is not None else ()
-        stats, *pong = self._jax.device_get((stats_dev,) + pong)
+        # the gated body's counters come home in the same pull as the
+        # series
+        counts = red_dev[8] if red_dev is not None and len(red_dev) > 8 else {}
+        stats, counts = self._jax.device_get((stats_dev, counts))
         self.series[t:t_end] = np.asarray(stats, np.int64)[: t_end - t]
         if (self.snapshot_round is not None
                 and t_end - 1 == self.snapshot_round):
@@ -711,9 +713,8 @@ class ShardedStepper:
             self.snapshot["is_app"] = self.cw.slot_app.copy()
             self.snapshot["slot_msg"] = self.cw.slot_msg.copy()
         self._rec.end()
-        for queries, chunks in pong:
-            self._rec.counter(self._cid["pong.queries"], int(queries))
-            self._rec.counter(self._cid["pong.chunks"], int(chunks))
+        for name, value in counts.items():
+            self._rec.counter(self._cid[name], int(value))
         t1 = self._clock()
         self._rec.begin(self._sid["retire"])
         self._retire(t_end, red_dev)

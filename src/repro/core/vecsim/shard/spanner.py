@@ -7,12 +7,20 @@ tables, ``crashed``/``ever_del`` — is row-block sharded over a 1-D
 index stream are replicated.  Per round, three things cross shards:
 
   * **frontier exchange** — the flood-forward + flush scatter (monolithic
-    phases 7/8) becomes a ring: each device's contribution plane for this
-    round's delivered columns (``vals`` = ``t + delay`` where sending,
-    ``INF`` elsewhere, with its global target rows) visits every device
-    via ``lax.ppermute``; each visit scatter-mins the rows it owns.
-    Scatter-min is associative/commutative on ints, so the result is
-    bit-equal to the monolithic global scatter regardless of hop order;
+    phases 7/8) becomes a ring: each device's contribution plane per
+    link slot (a value where sending, ``INF`` elsewhere, with its global
+    target rows) visits every device via ``lax.ppermute``; each visit
+    scatter-mins the rows it owns.  Scatter-min is associative and
+    commutative on ints, so the result is bit-equal to the monolithic
+    global scatter regardless of hop order.  On the jax backend the
+    forward half runs at the width of the round's columns with a new
+    delivery: the narrowest of :data:`FRONTIER_WIDTHS` that holds them
+    (the same on every shard, a ``pmax``), the columns picked by a
+    one-hot contraction, the link delay riding the ring and one
+    contraction expanding the result back to W; full width above that.
+    Flushes can reach columns whose wave has ended, so the flushing rows
+    go at full width, rank-compacted in chunks of :data:`PONG_ROWS`
+    rows;
   * **pong query ring** — pong detection reads ``delivered[q, s]`` at the
     gated link's remote target, for the candidate slots only (a live
     gate, flush pending, ping set, process up).  Their rows are compacted
@@ -68,8 +76,8 @@ __all__ = ["shard_span_runner", "shard_fast_span_runner",
            "shard_retire_kernels", "shard_hist_runner",
            "shard_column_gather",
            "resolve_shard_backend", "resolve_scan", "STATE_KEYS",
-           "INT16_LIMIT", "PONG_ROWS", "SEGMENT_PROGRAMS",
-           "PHASE_SCOPES"]
+           "INT16_LIMIT", "PONG_ROWS", "FRONTIER_WIDTHS",
+           "SEGMENT_PROGRAMS", "PHASE_SCOPES"]
 
 STATE_KEYS = _STATE_KEYS
 
@@ -88,6 +96,12 @@ PHASE_SCOPES = ("fold", "removals", "additions", "crashes", "broadcasts",
 # chunks, so a chunk is a fixed-shape gather of PONG_ROWS * K slots of
 # ``delivered`` and the read volume follows the live gates, not N.
 PONG_ROWS = 128
+
+# Widths the gated body's frontier exchange may run at, narrowest first:
+# the plane's lane tile and twice it.  A round runs at the narrowest
+# that holds every column with a new delivery (the wave's columns), or
+# at the window's full width when none does.
+FRONTIER_WIDTHS = (128, 256)
 
 # int16 ceiling of the fast scanned body: arrival rounds live in int16
 # planes there, with this value standing in for INF.  The driver only
@@ -184,8 +198,12 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
     ``(state, sched, ts, origins, rounds)`` and additionally returns the
     fused per-column retirement aggregates (``_column_partials``,
     psum'd), so a segment is one dispatch with no standalone reduce;
-    with gating live the aggregates carry a ninth entry, the segment's
-    int64 pong pair (candidate slots queried, chunks run).
+    the aggregates carry a ninth entry, the segment's int64 counters by
+    name where it has any: ``pong.queries`` and ``pong.chunks`` (the
+    candidate slots the pong query asked and the chunks it ran) with
+    gating live, ``frontier.columns`` and ``frontier.full_rounds`` (the
+    columns with a new delivery summed over rounds, and the rounds whose
+    exchange ran at full width) on the jax backend.
     Negative rounds in ``ts`` are padding and leave the state untouched.
     One compilation per (mesh, shape) signature, cached.
 
@@ -222,6 +240,142 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
     d = n_devices
     inf = jnp.int32(INF)
     perm = _shift(d)
+    # the per-round counters real_step returns, summed per segment
+    counter_names = ((("pong.queries", "pong.chunks") if pc and gating
+                      else ())
+                     + (() if pallas
+                        else ("frontier.columns", "frontier.full_rounds")))
+
+    def ranked(mask, m):
+        """Rank-compact the rows where ``mask`` holds, for chunks of
+        ``m`` rows: ``rank[r]`` counts them in ``[0, r]``, and every
+        shard runs the same number of chunks (a ring's ppermutes must
+        match)."""
+        rank = jnp.cumsum(mask, dtype=jnp.int32)
+        chunks = (rank[-1] + (m - 1)) // m
+        if d > 1:
+            chunks = jax.lax.pmax(chunks, "shard")
+        return rank, chunks
+
+    def chunk_rows(rank, c, m):
+        """Chunk ``c``'s rows, those of rank ``c*m+1 .. c*m+m``; the
+        row count (out of range, dropped) where there are fewer."""
+        want = c * m + jnp.arange(1, m + 1, dtype=jnp.int32)
+        return jnp.searchsorted(rank, want, side="left").astype(jnp.int32)
+
+    def ring_min(dest, vals, tgt, off, apply=None):
+        """Scatter-min ``vals`` (one row per global target ``tgt``) into
+        ``dest``: the plane visits every shard around the ring, and each
+        visit lands the rows that shard owns."""
+        n_loc = dest.shape[0]
+        tgt = tgt.astype(jnp.int32)
+        for hop in range(d):
+            if apply is not None:
+                dest = apply(dest, vals, tgt)
+            else:
+                tl = tgt - off
+                rows = jnp.where((tl >= 0) & (tl < n_loc), tl, n_loc)
+                dest = dest.at[rows, :].min(vals, mode="drop")
+            if hop < d - 1:
+                vals = jax.lax.ppermute(vals, "shard", perm)
+                tgt = jax.lax.ppermute(tgt, "shard", perm)
+        return dest
+
+    def forward(new_del, fwd, delay, adj, t, off, base):
+        """This round's flood-forward contributions (``t + delay`` over
+        every slot in ``fwd`` of a row with a new delivery), min-combined
+        into ``base`` (or a fresh INF plane).  The exchange runs at the
+        narrowest of :data:`FRONTIER_WIDTHS` that holds the columns with
+        a new delivery anywhere on the mesh, at full width above that.
+        Returns the plane, the active column count and whether the round
+        ran at full width."""
+        n_loc, width = new_del.shape
+
+        def exchange(plane, value, acc, none):
+            # one slot at a time, so one contribution plane is live
+            def one(kk, acc):
+                def col(a):
+                    return jax.lax.dynamic_index_in_dim(a, kk, axis=1,
+                                                        keepdims=False)
+                vals = jnp.where(plane & col(fwd)[:, None],
+                                 col(value)[:, None], none)
+                return ring_min(acc, vals, col(adj), off)
+            return jax.lax.fori_loop(0, k, one, acc)
+
+        def full(*base):
+            dest = base[0] if base else jnp.full((n_loc, width), inf)
+            return exchange(new_del, t + delay, dest, inf)
+
+        act = new_del.any(axis=0)
+        if d > 1:
+            # every shard picks the same width and columns
+            act = jax.lax.pmax(act.astype(jnp.int32), "shard") > 0
+        # the active columns' ranks: compact column c holds rank c + 1
+        rank = jnp.cumsum(act, dtype=jnp.int32)
+        n_act = rank[-1].astype(jnp.int64)
+        widths = [c for c in FRONTIER_WIDTHS if c < width]
+        if not widths:
+            return full(*base), n_act, jnp.int64(1)
+
+        def compact(c):
+            def run(*base):
+                # one-hot (W, c) selection of the active columns; with
+                # 0/1 operands and one term per sum both contractions
+                # are exact
+                pick = rank[:, None] == jnp.arange(1, c + 1,
+                                                   dtype=jnp.int32)
+                sel = (act[:, None] & pick).astype(jnp.bfloat16)
+                nd = jnp.dot(new_del.astype(jnp.bfloat16), sel) > 0
+                # the exchange carries the link delay, not the round, so
+                # int16 holds it (half the bytes of a scattered row)
+                none = jnp.int16(INT16_LIMIT)
+                lag = exchange(nd, delay.astype(jnp.int16),
+                               jnp.full((n_loc, c), none), none)
+                lag = jnp.where(lag < none, lag, 0).astype(jnp.bfloat16)
+                e = jnp.dot(lag, sel.T).astype(jnp.int32)
+                got = jnp.where(e > 0, t + e, inf)
+                return jnp.minimum(base[0], got) if base else got
+            return run
+
+        # bf16 holds every integer delay in [1, 256] exactly, and
+        # `e > 0` marks a contribution only if no delay is 0
+        fits = jnp.all(~fwd | ((delay >= 1) & (delay <= 256)))
+        if d > 1:
+            fits = jax.lax.pmin(fits.astype(jnp.int32), "shard") > 0
+        idx = sum((n_act > c).astype(jnp.int32) for c in widths)
+        idx = jnp.where(fits, idx, len(widths))
+        dest = jax.lax.switch(idx, [compact(c) for c in widths] + [full],
+                              *base)
+        return dest, n_act, (idx == len(widths)).astype(jnp.int64)
+
+    def flush_rows(dest, delivered, do, gate, delay, adj, is_app, t, off):
+        """Algorithm 2's flushes: each slot in ``do`` sends its row's
+        app deliveries in ``[gate, t)`` at ``t + delay``.  The window can
+        reach columns whose wave has ended, so these rows go at full
+        width, rank-compacted in chunks of :data:`PONG_ROWS` rows (about
+        one row flushes per round).  Returns the plane and the flushed
+        (row, column) count."""
+        n_loc, width = delivered.shape
+        m = min(PONG_ROWS, n_loc)
+        rank, chunks = ranked(do.any(axis=1), m)
+        slots = jnp.arange(k, dtype=jnp.int32)[None, :]
+
+        def one(c, carry):
+            dest, sent = carry
+            rows = chunk_rows(rank, c, m)
+            r = jnp.minimum(rows, n_loc - 1)
+            # point indices (row, slot) into the tables: see the pong query
+            at = (r[:, None], slots)
+            got = delivered[r][:, None, :]
+            win = ((got >= gate[at][:, :, None]) & (got < t)
+                   & (do[at] & (rows < n_loc)[:, None])[:, :, None]
+                   & is_app[None, None, :])
+            vals = jnp.where(win, (t + delay[at])[:, :, None], inf)
+            dest = ring_min(dest, vals.reshape(m * k, width),
+                            adj[at].reshape(-1), off)
+            return dest, sent + win.sum(dtype=jnp.int64)
+
+        return jax.lax.fori_loop(0, chunks, one, (dest, jnp.int64(0)))
 
     def real_step(sched, state, t, pending=None):
         deferred = pending is not None
@@ -322,8 +476,7 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 delivered = jnp.where(newly, t, delivered)
 
         # -- 6. pong detection: the compacted query ring ------------------ #
-        # (slots queried, chunks run) this round, for the stepper's counters
-        pong = jnp.zeros(2, jnp.int64)
+        counts = {}
         if pc and gating:
             with jax.named_scope("pong"):
                 # The monolithic fire mask, less its one remote conjunct
@@ -331,21 +484,12 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 cand = ((gate >= 0) & (flush == inf) & (ping >= 0)
                         & ~crashed[:, None])
                 m = min(PONG_ROWS, n_loc)
-                # rank[r] = candidate rows in [0, r]; chunk c asks the
-                # rows of rank c*m+1 .. c*m+m (n_loc where there are
-                # fewer: dropped)
-                rank = jnp.cumsum(cand.any(axis=1), dtype=jnp.int32)
-                chunks = (rank[-1] + (m - 1)) // m
-                if d > 1:
-                    # the ring's ppermutes must match on every shard
-                    chunks = jax.lax.pmax(chunks, "shard")
-                first = jnp.arange(1, m + 1, dtype=jnp.int32)
+                rank, chunks = ranked(cand.any(axis=1), m)
                 slots = jnp.arange(k, dtype=jnp.int32)[None, :]
 
                 def ask(c, carry):
                     flush, fired = carry
-                    rows = jnp.searchsorted(rank, c * m + first,
-                                            side="left").astype(jnp.int32)
+                    rows = chunk_rows(rank, c, m)
                     # point indices (row, slot): the chip lays the tables
                     # out row-minor, and a row-slice gather would re-lay
                     # each whole table out every round
@@ -376,77 +520,62 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 flush, fired = jax.lax.fori_loop(
                     0, chunks, ask, (flush, jnp.int64(0)))
                 stats = stats.at[4].set(fired)
-                pong = jnp.stack([cand.sum(dtype=jnp.int64),
-                                  chunks.astype(jnp.int64)])
+                counts["pong.queries"] = cand.sum(dtype=jnp.int64)
+                counts["pong.chunks"] = chunks.astype(jnp.int64)
 
         # -- 7+8. flush + forward: the frontier exchange ------------------ #
-        # Per link slot, the flush contributions (phase 7) and this
-        # round's flood-forward contributions (phase 8) min-combine into
-        # one (N/D, W) plane that rides the ring; both value t + delay
-        # over the same link, and scatter-min commutes, so the fusion is
-        # exact.  A slot flushed this round becomes safe *before* the
-        # forward pass, as in the monolithic body (gk_eff below).
+        # The flush contributions (phase 7) and this round's flood-forward
+        # contributions (phase 8) both value t + delay over the same link,
+        # and scatter-min commutes, so each may take its own path into one
+        # (N/D, W) plane.  A slot flushed this round becomes safe *before*
+        # the forward pass, as in the monolithic body (gate_eff below).
         with jax.named_scope("flush_forward"):
-            if not pallas:
+            if pc and gating:
+                flushing = flush == t
+                do = flushing & active & ~crashed[:, None]
+                gate_eff = jnp.where(flushing, -1, gate)
+            else:
+                do = jnp.zeros_like(active)
+                gate_eff = gate
+            ok = active & (gate_eff < 0) & (adj >= 0) & ~crashed[:, None]
+            elig_cnt = ok.sum(axis=1, dtype=jnp.int64)
+            # deferred mode writes a fresh pending plane (folded into arr
+            # at the next round's entry); immediate mode min-combines
+            # into arr directly, as the windowed reference does
+            base = () if deferred else (arr,)
+            if pallas:
+                # slot kernels: combined flush+forward contribution planes
+                # (a row with a delivery this round is never crashed, so
+                # the jax body's has_new conjunct is implied by new_del)
+                dest = base[0] if base else jnp.full_like(arr, inf)
+                flush_sent = jnp.int64(0)
+                for kk in range(k):
+                    vals, win_cnt = kx.slot_frontier(
+                        delivered, gate[:, kk], delay[:, kk], do[:, kk],
+                        ok[:, kk], is_app, t, gating=pc and gating)
+                    flush_sent += win_cnt.astype(jnp.int64)
+                    dest = ring_min(
+                        dest, vals, adj[:, kk], off,
+                        apply=lambda a, v, g: kx.ring_apply(a, v, g, off))
+            else:
                 new_del = delivered == t
                 napp = (new_del & is_app[None, :]).sum(axis=1)
                 nping = (new_del & ~is_app[None, :]).sum(axis=1)
                 has_new = new_del.any(axis=1) & ~crashed
-            elig_cnt = jnp.zeros(n_loc, jnp.int64)
-            flush_sent = jnp.int64(0)
-            # deferred mode scatters into a fresh pending plane (folded
-            # into arr at the next round's entry); immediate mode
-            # scatters into arr directly, as the windowed reference does
-            dest = jnp.full_like(arr, inf) if deferred else arr
-            for kk in range(k):
-                gk = gate[:, kk]
-                dk = (t + delay[:, kk])[:, None].astype(jnp.int32)
+                dest, cols, full = forward(new_del, ok & has_new[:, None],
+                                           delay, adj, t, off, base)
+                counts["frontier.columns"] = cols
+                counts["frontier.full_rounds"] = full
+                flush_sent = jnp.int64(0)
                 if pc and gating:
-                    do = (flush[:, kk] == t) & active[:, kk] & ~crashed
-                    gk_eff = jnp.where(flush[:, kk] == t, -1, gk)
-                else:
-                    do = jnp.zeros_like(crashed)
-                    gk_eff = gk
-                ok = (active[:, kk] & (gk_eff < 0) & (adj[:, kk] >= 0)
-                      & ~crashed)
-                elig_cnt += ok.astype(jnp.int64)
-                if pallas:
-                    # slot kernel: combined flush+forward contribution
-                    # plane (a row with a delivery this round is never
-                    # crashed, so the jax body's has_new conjunct is
-                    # implied by new_del)
-                    vals, win_cnt = kx.slot_frontier(
-                        delivered, gk, delay[:, kk], do, ok, is_app, t,
-                        gating=pc and gating)
-                    flush_sent += win_cnt.astype(jnp.int64)
-                else:
-                    if pc and gating:
-                        win = ((delivered >= gk[:, None]) & (delivered < t)
-                               & do[:, None] & is_app[None, :])
-                        flush_sent += win.sum().astype(jnp.int64)
-                    fwd = ok & has_new
-                    vals = jnp.where(new_del & fwd[:, None], dk, inf)
-                    if pc and gating:
-                        vals = jnp.minimum(vals, jnp.where(win, dk, inf))
-                tgt = adj[:, kk].astype(jnp.int32)
-                for hop in range(d):
-                    if pallas:
-                        dest = kx.ring_apply(dest, vals, tgt, off)
-                    else:
-                        tl = tgt - off
-                        rows = jnp.where((tl >= 0) & (tl < n_loc), tl,
-                                         n_loc)
-                        dest = dest.at[rows, :].min(vals, mode="drop")
-                    if hop < d - 1:
-                        vals = jax.lax.ppermute(vals, "shard", perm)
-                        tgt = jax.lax.ppermute(tgt, "shard", perm)
+                    dest, flush_sent = flush_rows(dest, delivered, do, gate,
+                                                  delay, adj, is_app, t, off)
             if not deferred:
                 arr = dest
             if pc and gating:
-                cleared = flush == t
-                gate = jnp.where(cleared, -1, gate)
-                ping = jnp.where(cleared, -1, ping)
-                flush = jnp.where(cleared, inf, flush)
+                gate = jnp.where(flushing, -1, gate)
+                ping = jnp.where(flushing, -1, ping)
+                flush = jnp.where(flushing, inf, flush)
         with jax.named_scope("stats"):
             stats = stats.at[0].set(napp.sum().astype(jnp.int64))
             stats = stats.at[1].set(
@@ -460,8 +589,8 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
         out = (arr, delivered, adj, delay, active, gate, flush, ping,
                crashed, ever_del)
         if deferred:
-            return (out, dest), stats, pong
-        return out, stats, pong
+            return (out, dest), stats, counts
+        return out, stats, counts
 
     def step(sched, state, t):
         t = t.astype(jnp.int32)
@@ -474,13 +603,13 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
     if scan:
         def scan_step(sched, carry, t):
             t = t.astype(jnp.int32)
-            carry, stats, pong = jax.lax.cond(
+            carry, stats, counts = jax.lax.cond(
                 t >= 0,
                 lambda c: real_step(sched, c[0], t, c[1]),
                 lambda c: (c, jnp.zeros(len(SERIES_FIELDS), jnp.int64),
-                           jnp.zeros(2, jnp.int64)),
+                           {n: jnp.int64(0) for n in counter_names}),
                 carry)
-            return carry, (stats, pong)
+            return carry, (stats, counts)
 
         def segment_gated(state, sched, ts, origins, rounds):
             is_app = sched["is_app"]
@@ -493,7 +622,7 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 sch["is_app"] = is_app
                 return scan_step(sch, carry, t)
 
-            (state, pending), (stats, pong) = jax.lax.scan(
+            (state, pending), (stats, counts) = jax.lax.scan(
                 body, (tuple(state), pending0), (ts, events))
             # residual fold: the last round's in-flight frontier (padding
             # rounds skip real_step, so pending survives to here intact)
@@ -510,12 +639,14 @@ def shard_span_runner(n_devices: int, k: int, pc: bool, always_gate: bool,
                 red = tuple(jax.lax.psum(x, "shard")
                             for x in _column_partials(state, origins,
                                                       rounds, off))
-            if pc and gating:
-                # the segment's pong (slots queried, chunks run): the
-                # chunk count is already uniform across shards
-                pong = pong.sum(axis=0)
-                red += (jnp.stack([jax.lax.psum(pong[0], "shard"),
-                                   pong[1]]),)
+            if counts:
+                # the segment's counters; all but the pong query's slot
+                # count are already uniform across shards
+                counts = {n: v.sum() for n, v in counts.items()}
+                if "pong.queries" in counts:
+                    counts["pong.queries"] = jax.lax.psum(
+                        counts["pong.queries"], "shard")
+                red += (counts,)
             return state, stats, red
         span = segment_gated
     else:

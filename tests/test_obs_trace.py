@@ -323,3 +323,58 @@ def test_pong_counters_per_gated_dispatch():
     _, got = _pong_counters(static_scenario(3, 64))
     assert got["segment.dispatch"]
     assert not got["pong.queries"] and not got["pong.chunks"]
+
+
+def _frontier_counters(scn, w, seg_len):
+    obs = EngineObs(histograms=False, spans=True)
+    st = ShardedStepper(scn, w, n_devices=1, seg_len=seg_len, scan="on",
+                        collect="full", obs=obs)
+    while not st.done:
+        st.advance()
+    res = st.finish()
+    evs = obs.spans.events()
+    got = {name: [e for e in evs if e["name"] == name]
+           for name in ("segment.dispatch", "frontier.columns",
+                        "frontier.full_rounds")}
+    return res, got
+
+
+def _wave_columns(delivered):
+    """Sum over rounds of the messages (app and ping) delivered in that
+    round somewhere: each one's distinct delivery rounds."""
+    return sum(len(np.unique(col[col >= 0])) for col in delivered.T)
+
+
+def test_frontier_counters_per_gated_dispatch():
+    """Every gated dispatch records the columns its frontier exchange
+    found with a new delivery, summed over its rounds, and the rounds it
+    ran at full width; the columns add up to the run's distinct
+    (message, round) deliveries.  A window of at most 128 columns runs
+    every round at full width.  At W=512, one round per dispatch, dense
+    waves run at 128, at 256 and at full width, which a round takes
+    exactly when more than 256 columns are active."""
+    from vecsim_cases import build
+
+    scn = churn_scenario(3, 64)
+    assert scn.m_total <= 128
+    res, got = _frontier_counters(scn, scn.m_total, 8)
+    n = len(got["segment.dispatch"])
+    assert n > 1
+    for name in ("frontier.columns", "frontier.full_rounds"):
+        assert len(got[name]) == n, name
+        assert all(e["kind"] == "counter" for e in got[name])
+    assert sum(e["value"] for e in got["frontier.full_rounds"]) \
+        == scn.rounds
+    assert sum(e["value"] for e in got["frontier.columns"]) \
+        == _wave_columns(res.delivered) > 0
+
+    scn = build("burst512", 7, 64)
+    res, got = _frontier_counters(scn, scn.m_total, 1)
+    cols = np.array([e["value"] for e in got["frontier.columns"]])
+    full = np.array([e["value"] for e in got["frontier.full_rounds"]])
+    assert len(cols) == len(full) == scn.rounds
+    assert cols.sum() == _wave_columns(res.delivered)
+    np.testing.assert_array_equal(full, cols > 256)
+    assert ((cols > 0) & (cols <= 128)).any()
+    assert ((cols > 128) & (cols <= 256)).any()
+    assert (cols > 256).any()

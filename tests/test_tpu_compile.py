@@ -88,20 +88,29 @@ def test_windowed_span_runner_compiles_for_v5e(topo):
     _assert_fits(compiled)
 
 
-def _gather_sizes(hlo: str):
-    """Element count of every ``gather`` result in compiled HLO text."""
-    return [int(np.prod([int(x) for x in dims.split(",") if x]))
+def _gather_shapes(hlo: str):
+    """Shape of every ``gather`` result in compiled HLO text."""
+    return [tuple(int(x) for x in dims.split(",") if x)
             for dims in re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", hlo)]
+
+
+def _scatter_widths(hlo: str, rows: int):
+    """Column counts of every ``scatter`` result of ``rows`` rows in
+    compiled HLO text."""
+    return {int(c) for c in re.findall(
+        r"= \w+\[%d,(\d+)\]\S* scatter\(" % rows, hlo)}
 
 
 def test_sharded_scan_generic_body_compiles_for_v5e(topo, monkeypatch):
     """The sharded engine's scanned generic body (gating live: a churn
-    scenario) on one described device; the runner's mesh is steered onto
-    the described device here, in the test.  Its pong query reads only
-    the candidate rows' slots, in chunks: no gather spans every (row,
-    slot) of the (N, K) tables."""
+    scenario) at the benchmark's W=512 on one described device; the
+    runner's mesh is steered onto the described device here, in the
+    test.  Its pong query reads only the candidate rows' slots, in
+    chunks: no gather spans every (row, slot) of the (N, K) tables.  Its
+    frontier exchange scatters at the compact widths and, as the
+    fallback, at full width."""
     from repro.core.vecsim.shard import spanner
-    n, w, seg_len = 1 << 14, 128, 8
+    n, w, seg_len = 1 << 14, 512, 8
     scn = _scenario(n, traffic="uniform", dynamics="churn")
     assert scn.n_adds > 0
     mesh = jax.sharding.Mesh(np.array(topo.devices[:1]), ("shard",))
@@ -123,9 +132,11 @@ def test_sharded_scan_generic_body_compiles_for_v5e(topo, monkeypatch):
     _assert_fits(compiled)
     hlo = compiled.as_text()
     assert "input_output_alias" in hlo
-    sizes = _gather_sizes(hlo)
-    assert sizes and n * scn.k not in sizes, sizes
-    assert spanner.PONG_ROWS * scn.k in sizes, sizes
+    shapes = _gather_shapes(hlo)
+    assert shapes and (n, scn.k) not in shapes, shapes
+    assert (n * scn.k,) not in shapes, shapes
+    assert (spanner.PONG_ROWS, scn.k) in shapes, shapes
+    assert _scatter_widths(hlo, n) >= set(spanner.FRONTIER_WIDTHS) | {w}
 
 
 def test_sharded_hist_fold_compiles_for_v5e(topo, monkeypatch):

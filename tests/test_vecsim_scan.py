@@ -69,6 +69,7 @@ def _run_pair(scn, w, seg_len, **kw):
 @pytest.mark.parametrize("builder,seed", [
     ("static", 3), ("link_add", 5), ("churn", 7), ("crash", 9),
     ("partition", 11), ("sustained_kreg", 13), ("waves", 15),
+    ("burst256", 7), ("burst512", 7),
 ])
 def test_scan_single_device_byte_identical(builder, seed):
     scn = build(builder, seed, 64)
@@ -118,6 +119,46 @@ def test_scan_horizon_expiry_parity():
     on, _ = _run_pair(scn, scn.m_total, 4, horizon=4)
     assert win.expired.any()
     _assert_matches(win, on)
+
+
+def _flush_rows_most(scn, w):
+    """The most rows that flush on some link slot in one round, read
+    from the stepped body's state before each round."""
+    from repro.core.vecsim.shard.driver import ShardedStepper
+    st = ShardedStepper(scn, w, n_devices=1, seg_len=1, scan="off")
+    most = 0
+    while not st.done:
+        h = st.host_state()
+        rows = ((h["flush"] == st.t) & h["active"]
+                & ~h["crashed"][:, None]).any(axis=1)
+        most = max(most, int(rows.sum()))
+        st.advance()
+    return most
+
+
+def test_flush_rows_past_one_chunk(monkeypatch):
+    """The flush path takes its rows in rank-compacted chunks of
+    ``PONG_ROWS``; with the chunk cut to one and two rows, a round
+    flushes more rows than one chunk holds, on a window wide enough for
+    the compact exchange.  Byte-identical to the windowed engine and to
+    scan="off"."""
+    from repro.core.vecsim.shard import spanner
+    scn = build("burst256", 7, 64)
+    w = scn.m_total
+    most = _flush_rows_most(scn, w)
+    win = execute_windowed(scn, w, backend="numpy", collect="full",
+                           seg_len=8)
+    try:
+        for rows in (1, 2):
+            assert most > rows
+            # rebuild the runners so they trace with the chunk patched in
+            monkeypatch.setattr(spanner, "PONG_ROWS", rows)
+            spanner.shard_span_runner.cache_clear()
+            on, _ = _run_pair(scn, w, 8)
+            _assert_matches(win, on)
+    finally:
+        monkeypatch.undo()
+        spanner.shard_span_runner.cache_clear()
 
 
 # --------------------------------------------------------------------- #
@@ -249,7 +290,8 @@ def test_scan_two_devices_matrix_subprocess():
     run_shard_matrix_subprocess(
         [("churn", 7, 64, 1.0, 8),
          ("link_add", 5, 256, 1.0, 16),   # gating at the larger N
-         ("crash", 9, 64, 0.5, 8)],       # retirement recycling
+         ("crash", 9, 64, 0.5, 8),        # retirement recycling
+         ("burst512", 7, 64, 1.0, 8)],    # every exchange width
         shards=2, scan="on")
 
 
@@ -258,7 +300,8 @@ def test_scan_four_devices_matrix_subprocess():
         [("churn", 8, 256, 1.0, 16),
          ("crash", 2, 256, 1.0, 16),
          ("waves", 4, 50, 1.0, 8),        # 50 % 4 != 0: padding rows
-         ("static", 3, 64, 1.0, 7)],      # ragged final segment
+         ("static", 3, 64, 1.0, 7),       # ragged final segment
+         ("burst256", 7, 64, 1.0, 8)],    # compact and full exchange
         shards=4, scan="on")
 
 

@@ -35,7 +35,7 @@ def _assert_matches(win, sh):
 
 @pytest.mark.parametrize("builder,seed", [
     ("static", 3), ("link_add", 5), ("churn", 7), ("crash", 9),
-    ("partition", 11), ("sustained_kreg", 13),
+    ("partition", 11), ("sustained_kreg", 13), ("burst512", 7),
 ])
 def test_sharded_single_device_byte_identical(builder, seed):
     """D=1: the mesh program with no cross-shard traffic still matches
@@ -147,7 +147,8 @@ def test_sharded_two_devices_matrix_subprocess():
         [("churn", 7, 64, 1.0, 8),
          ("crash", 9, 64, 1.0, 16),
          ("link_add", 5, 256, 1.0, 16),    # gating at the larger N
-         ("churn", 3, 64, 0.5, 8)],        # retirement recycling
+         ("churn", 3, 64, 0.5, 8),         # retirement recycling
+         ("burst256", 7, 64, 1.0, 8)],     # compact and full exchange
         shards=2)
 
 
@@ -218,45 +219,63 @@ def test_device_fold_psums_across_two_shards_subprocess():
 
 
 _PONG_CHUNKS_SNIPPET = """
-from repro.core.vecsim.shard import spanner
+from repro.core.vecsim.shard import driver, spanner
 from repro.obs.spans import EngineObs
 
 scn = build("churn", 7, 64)
-w = scn.m_total
-win = execute_windowed(scn, w, backend="numpy", collect="full", seg_len=8)
-for rows in (1, 2):
-    # rebuild the runners so they trace with the chunk size patched in
-    spanner.PONG_ROWS = rows
-    spanner.shard_span_runner.cache_clear()
-    for scan, seg in (("on", 1), ("on", 8), ("off", 8)):
-        obs = EngineObs(histograms=False, spans=True)
-        sh = execute_sharded(scn, w, n_devices={shards}, collect="full",
-                             seg_len=seg, scan=scan, obs=obs)
-        tag = (rows, scan, seg)
-        np.testing.assert_array_equal(win.delivered, sh.delivered)
-        np.testing.assert_array_equal(win.series, sh.series)
-        assert win.stats == sh.stats, tag
-        for key in win.state:
-            np.testing.assert_array_equal(win.state[key], sh.state[key],
-                                          err_msg=str(tag) + key)
-        if seg == 1:
-            # one round per segment: the counters are per round
-            chunks = [e["value"] for e in obs.spans.events()
-                      if e["name"] == "pong.chunks"]
-            assert len(chunks) == scn.rounds, tag
-            assert min(chunks) == 0, tag
-            if rows == 1:
-                assert max(chunks) >= 2, (tag, max(chunks))
+# a round in which three rows flush: more than one chunk of either size
+st = driver.ShardedStepper(scn, scn.m_total, n_devices=1, seg_len=1,
+                           scan="off")
+most = 0
+while not st.done:
+    h = st.host_state()
+    most = max(most, int(((h["flush"] == st.t) & h["active"]
+                          & ~h["crashed"][:, None]).any(axis=1).sum()))
+    st.advance()
+assert most > 2, most
+# the window's own width, then one the compact exchange runs in
+cases = [(w, rows, scan, seg) for w in (scn.m_total, 256) for rows in (1, 2)
+         for scan, seg in (("on", 1), ("on", 8), ("off", 8))]
+win = {}
+for w, rows, scan, seg in cases:
+    if w not in win:
+        win[w] = execute_windowed(scn, w, backend="numpy", collect="full",
+                                  seg_len=8)
+    ref = win[w]
+    if spanner.PONG_ROWS != rows:
+        # rebuild the runners so they trace with the chunk size patched in
+        spanner.PONG_ROWS = rows
+        spanner.shard_span_runner.cache_clear()
+    obs = EngineObs(histograms=False, spans=True)
+    sh = execute_sharded(scn, w, n_devices={shards}, collect="full",
+                         seg_len=seg, scan=scan, obs=obs)
+    tag = (w, rows, scan, seg)
+    np.testing.assert_array_equal(ref.delivered, sh.delivered)
+    np.testing.assert_array_equal(ref.series, sh.series)
+    assert ref.stats == sh.stats, tag
+    for key in ref.state:
+        np.testing.assert_array_equal(ref.state[key], sh.state[key],
+                                      err_msg=str(tag) + key)
+    if seg == 1:
+        # one round per segment: the counters are per round
+        chunks = [e["value"] for e in obs.spans.events()
+                  if e["name"] == "pong.chunks"]
+        assert len(chunks) == scn.rounds, tag
+        assert min(chunks) == 0, tag
+        if rows == 1:
+            assert max(chunks) >= 2, (tag, max(chunks))
 print("PONG_OK")
 """
 
 
 @pytest.mark.parametrize("shards", [1, 2, 4])
 def test_pong_chunks_match_windowed_subprocess(shards):
-    """The compacted pong query with one or two rows per chunk, so that
-    one round runs several chunks (and rounds with no candidate run
-    none): the scanned and the stepped sharded bodies stay byte-identical
-    to the windowed engine on a churn scenario."""
+    """The compacted pong query and flush rows with one or two rows per
+    chunk, so that one round runs several chunks (and rounds with no
+    candidate run none), at the window's own width and at one where the
+    frontier exchange runs compact: the scanned and the stepped sharded
+    bodies stay byte-identical to the windowed engine on a churn
+    scenario."""
     out = run_shard_matrix_subprocess(
         [], shards=shards,
         extra=_PONG_CHUNKS_SNIPPET.replace("{shards}", str(shards)))

@@ -16,6 +16,17 @@ from repro.core.vecsim import (churn_scenario, churn_wave_scenario,
                                partition_heal_scenario, static_scenario,
                                sustained_scenario)
 
+
+def _burst(seed: int, n: int, w: int):
+    """Dense broadcast waves under link churn, ``m_total == w``: at
+    N=64 some rounds hold more than 128 (and at w=512 more than 256)
+    columns with a new delivery, so the gated body's frontier exchange
+    runs at each of its widths."""
+    n_adds = max(2, n // 8)
+    return churn_scenario(seed, n, m_app=w - n_adds, n_adds=n_adds,
+                          churn_window=6)
+
+
 BUILDERS = {
     "static": lambda seed, n: static_scenario(seed, n),
     "link_add": lambda seed, n: link_add_scenario(seed, n),
@@ -32,9 +43,16 @@ BUILDERS = {
         traffic="bursty", max_delay=2),
 }
 
+# Cases for the exactness suites only: too wide for the fuzz suite and
+# the interpret-mode kernel sweeps that run every builder
+WIDE = {
+    "burst256": lambda seed, n: _burst(seed, n, 256),
+    "burst512": lambda seed, n: _burst(seed, n, 512),
+}
+
 
 def build(name: str, seed: int, n: int):
-    return BUILDERS[name](seed, n)
+    return (WIDE[name] if name in WIDE else BUILDERS[name])(seed, n)
 
 
 # --------------------------------------------------------------------- #
